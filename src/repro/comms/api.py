@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import axis_size, shard_map
+from ..compat import shard_map
 from ..core.health import (
     FaultEvent,
     HealthError,
@@ -460,7 +460,7 @@ class CommContext:
         if self.mesh is not None:
             return {n: self.mesh.shape[n] for n in names}
         # trace-time: inside shard_map the ambient axis env knows the sizes
-        return {n: axis_size(n) for n in names}
+        return {n: lax.axis_size(n) for n in names}
 
     # -- planning (cached) ---------------------------------------------------
     def _effective_regime(self, mode: Optional[str] = None,
@@ -901,7 +901,7 @@ def _in_axis_env(names: Sequence[str]) -> bool:
     tracing inside a shard_map body over these axes."""
     try:
         for n in names:
-            axis_size(n)
+            lax.axis_size(n)
         return True
     except Exception:
         return False
@@ -958,7 +958,7 @@ def _local_plan(ctx, collective, names, x, axis, *, mode, num_chunks,
     full-length local array (RS/AR input).  ``regime`` forces a plan
     family; None resolves it from the policy + these per-call overrides
     (a mode/chunk override plans in the bandwidth family)."""
-    sizes = {n: axis_size(n) for n in names}
+    sizes = {n: lax.axis_size(n) for n in names}
     n_total = math.prod(sizes.values())
     nbytes = x.size * x.dtype.itemsize
     shard_bytes = nbytes if scattered else nbytes / n_total
@@ -1132,7 +1132,7 @@ def all_reduce(
     if axis < 0:
         axis += x.ndim
     if _in_axis_env(names):
-        n_total = math.prod(axis_size(n) for n in names)
+        n_total = math.prod(lax.axis_size(n) for n in names)
         if x.shape[axis] % n_total:
             return lax.psum(x, names)
         plan, _ = _local_plan(ctx, "ar", names, x, axis,
@@ -1177,7 +1177,7 @@ def all_to_all(
     if axis < 0:
         axis += x.ndim
     if _in_axis_env(names):
-        n_total = math.prod(axis_size(n) for n in names)
+        n_total = math.prod(lax.axis_size(n) for n in names)
         plan = ctx.plan("a2a", x.size * x.dtype.itemsize, axes=names,
                         shape=tuple(x.shape), dtype=x.dtype)
         plan = _apply_overrides(plan, mode, num_chunks)
@@ -1298,7 +1298,7 @@ def matmul_reduce_scatter(
         axis += h.ndim
 
     def run_local(hl, wl):
-        sizes = {n: axis_size(n) for n in names}
+        sizes = {n: lax.axis_size(n) for n in names}
         n_total = math.prod(sizes.values())
         out_bytes = (hl.size // hl.shape[-1]) * wl.shape[-1] * hl.dtype.itemsize
         plan = ctx.plan("rs", out_bytes / n_total, axes=names,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import axis_size
 from .staged_allgather import staged_all_gather
 from .staged_collectives import staged_reduce_scatter
 
@@ -46,7 +45,7 @@ def reduce_scatter(x: jax.Array, axis_name: str, axis: int = 0) -> jax.Array:
 
 def ring_all_gather(x: jax.Array, axis_name: str, axis: int = 0) -> jax.Array:
     """Classic N-1-step ring all-gather via ppermute (paper's Ring baseline)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -90,7 +89,7 @@ def _ne_tables(n: int) -> Tuple[np.ndarray, np.ndarray]:
 
 def neighbor_exchange_all_gather(x: jax.Array, axis_name: str, axis: int = 0) -> jax.Array:
     """Neighbor-Exchange all-gather (Chen et al. 2005): N/2 exchange steps."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n % 2:
         raise ValueError("neighbor exchange needs an even axis size")
     if n == 2:

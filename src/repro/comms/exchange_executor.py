@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..core.plan_ir import CollectivePlan, PlanStage
 from .ring_executor import _maybe_inject
 
@@ -82,7 +81,7 @@ def _axis_groups(stages: Sequence[PlanStage]) -> List[Tuple[str, int]]:
             f"axes {[s.axis for s in stages]}")
     out = []
     for name, k in groups:
-        m = axis_size(name)
+        m = lax.axis_size(name)
         if m != 1 << k:
             raise ValueError(
                 f"axis {name!r} has size {m} but the plan carries {k} "

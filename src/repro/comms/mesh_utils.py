@@ -17,9 +17,9 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 
-from ..compat import auto_axis_types, make_mesh
+from ..compat import make_mesh
 
-__all__ = ["make_factorized_mesh", "auto_axis_types"]
+__all__ = ["make_factorized_mesh"]
 
 
 def make_factorized_mesh(

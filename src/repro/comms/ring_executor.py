@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from .staged_collectives import (
     _a2a_merge_digits,
     _a2a_split_digits,
@@ -140,7 +139,7 @@ def ring_all_gather_stage(x: jax.Array, name: str) -> jax.Array:
     against the sends.  One flip+roll at the end rotates arrival order into
     origin order — a single local copy instead of m buffer updates.
     """
-    m = axis_size(name)
+    m = lax.axis_size(name)
     if m == 1:
         return x[None]
     idx = lax.axis_index(name)
@@ -171,7 +170,7 @@ def ring_reduce_scatter_stage(
     b-th of m contiguous slices of ``y``) — the collective-matmul fusion
     plugs in a just-in-time block matmul here.
     """
-    m = axis_size(name)
+    m = lax.axis_size(name)
     if m == 1:
         return y if block_fn is None else block_fn(0)
     if block_fn is None:
@@ -207,7 +206,7 @@ def ring_all_to_all_stage(y: jax.Array, name: str) -> jax.Array:
     origin (idx - t) mod m, so the same flip+roll as the all-gather ring
     restores origin order in one local copy.
     """
-    m = axis_size(name)
+    m = lax.axis_size(name)
     if m == 1:
         return y
     if y.shape[0] != m:

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-from ..compat import axis_size
 from ..core.plan_ir import CollectivePlan
 from ..core.planner import (
     LinkSpec,
@@ -70,7 +69,7 @@ def _check_order(order, axis_names) -> Tuple[str, ...]:
 
 
 def _axis_sizes(axis_names: Sequence[str]) -> Dict[str, int]:
-    return {n: axis_size(n) for n in axis_names}
+    return {n: lax.axis_size(n) for n in axis_names}
 
 
 def _permute_blocks_to_order(y, axis_names, order, sizes):

@@ -1,58 +1,17 @@
-"""Version-compat shims for the pinned JAX / Pallas wheels.
+"""Two shims over JAX (0.9) that carry this project's defaults.
 
-The codebase targets the current public API names (``jax.shard_map``,
-``jax.sharding.AxisType``, ``pltpu.CompilerParams``); the pinned wheel
-predates some of them.  Every call site goes through this module so a
-version bump is a one-file fix:
-
-  * ``AxisType`` / ``axis_types=`` on ``jax.make_mesh`` — newer JAX only.
-    ``make_mesh`` passes the kwarg when supported and omits it otherwise
-    (meshes default to Auto axes on old versions anyway).
-  * ``jax.shard_map(..., check_vma=)`` — falls back to
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-  * ``pltpu.CompilerParams`` — renamed from ``pltpu.TPUCompilerParams``.
+  * ``make_mesh`` — ``jax.make_mesh`` with Auto axis types.  JAX's own
+    default is Explicit; the GSPMD paths and the planned collectives here
+    expect Auto axes.
+  * ``shard_map`` — ``jax.shard_map`` with replication checking off.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
 
-__all__ = [
-    "get_axis_type",
-    "auto_axis_types",
-    "make_mesh",
-    "shard_map",
-    "axis_size",
-    "cost_analysis",
-    "tpu_compiler_params",
-]
-
-
-def axis_size(name: str) -> int:
-    """Static size of a named mesh axis inside shard_map.
-
-    ``jax.lax.axis_size`` on new JAX; on old versions ``jax.core.axis_frame``
-    resolves the name in the ambient axis env (returning either the size
-    itself or a frame carrying it, depending on the exact version).
-    """
-    lax_size = getattr(jax.lax, "axis_size", None)
-    if lax_size is not None:
-        return lax_size(name)
-    frame = jax.core.axis_frame(name)
-    return frame if isinstance(frame, int) else frame.size
-
-
-def get_axis_type() -> Optional[Any]:
-    """``jax.sharding.AxisType.Auto`` where it exists, else ``None``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return None if axis_type is None else axis_type.Auto
-
-
-def auto_axis_types(n: int) -> Optional[Tuple[Any, ...]]:
-    """``(AxisType.Auto,) * n`` on new JAX, ``None`` (omit kwarg) on old."""
-    auto = get_axis_type()
-    return None if auto is None else (auto,) * n
+__all__ = ["make_mesh", "shard_map"]
 
 
 def make_mesh(
@@ -61,43 +20,16 @@ def make_mesh(
     *,
     devices=None,
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types when the kwarg exists."""
+    """``jax.make_mesh`` with every axis Auto."""
     axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    kwargs: dict = {}
-    types = auto_axis_types(len(axis_shapes))
-    if types is not None:
-        kwargs["axis_types"] = types
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices, **kwargs)
+    return jax.make_mesh(
+        axis_shapes, tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_shapes),
+        devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any JAX version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
-
-
-def cost_analysis(compiled) -> dict:
-    """Dict form of ``Compiled.cost_analysis()`` on any JAX version (old
-    versions return a one-element list of per-program dicts)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
-def tpu_compiler_params(**kwargs):
-    """Build Pallas-TPU compiler params under either class name."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..comms.ring_executor import (
     _merge_device_axis,
     _resolve_modes,
@@ -80,7 +79,7 @@ def _fused_ring_ag_stage(
     transpose applies to both.  The matmul of the block received at hop t
     runs while hop t+1 forwards it: the gather hides behind the MXU.
     """
-    m = axis_size(name)
+    m = lax.axis_size(name)
     if m == 1:
         return cur[None], [o[None] for o in outs]
     idx = lax.axis_index(name)

@@ -1,9 +1,17 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
 Backend selection:
-  * ``ref``     — pure-jnp oracles (default on CPU; fully differentiable)
-  * ``pallas``  — pl.pallas_call kernels (TPU target; ``interpret=True``
-                  executes the kernel body on CPU for validation)
+  * ``ref``     — pure-jnp oracles (the default; fully differentiable)
+  * ``pallas``  — pl.pallas_call kernels, compiled for the TPU.  The entry
+                  points select it when they find a TPU
+                  (``repro.launch.device.select_kernel_backend``).  Kernels
+                  run in interpret mode only when a caller asks for it
+                  (``set_backend("pallas", interpret=True)``, as the CPU
+                  tests do).
+
+Single-query decode attention (a ``kv_mask`` over the padded cache) has no
+kernel: :func:`flash_attention` runs it on the reference path under either
+backend (``DECODE_ATTENTION_PATH`` names it for the server's report).
 
 Kernel forwards are wrapped in ``jax.custom_vjp`` with the ref backward, so
 the pallas backend remains trainable without hand-written backward kernels
@@ -12,7 +20,6 @@ the pallas backend remains trainable without hand-written backward kernels
 from __future__ import annotations
 
 import contextlib
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -32,10 +39,13 @@ __all__ = [
     "swiglu",
     "flash_attention",
     "rwkv6_scan",
+    "DECODE_ATTENTION_PATH",
 ]
 
 _BACKEND = "ref"
-_INTERPRET = True  # no real TPU in this container; kernels run interpreted
+_INTERPRET = False
+#: what decode attention (``kv_mask`` set) runs on, whatever the backend
+DECODE_ATTENTION_PATH = "ref (masked jnp attention; no decode kernel)"
 #: key-length threshold above which the ref backend switches to the chunked
 #: online-softmax attention (never materializes the S x T logits)
 FLASH_CHUNK_THRESHOLD = 4096
@@ -56,13 +66,13 @@ def get_backend() -> str:
 
 
 @contextlib.contextmanager
-def backend_scope(name: str):
-    prev = _BACKEND
-    set_backend(name)
+def backend_scope(name: str, *, interpret: Optional[bool] = None):
+    prev = (_BACKEND, _INTERPRET)
+    set_backend(name, interpret=interpret)
     try:
         yield
     finally:
-        set_backend(prev)
+        set_backend(prev[0], interpret=prev[1])
 
 
 def _ref_vjp(pallas_fn, ref_fn):
@@ -114,8 +124,8 @@ def flash_attention(
     kv_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
     if _BACKEND == "ref" or kv_mask is not None:
-        # the kernel path does not implement arbitrary kv masks (decode uses
-        # the ref path / sharded-KV combine instead)
+        # no kernel takes an arbitrary kv mask: decode runs here under both
+        # backends (DECODE_ATTENTION_PATH)
         if k.shape[2] > FLASH_CHUNK_THRESHOLD and q.shape[2] > 1:
             # chunked online softmax for long prefill/train; single-query
             # decode keeps the direct masked path (scan overhead loses there)
@@ -144,12 +154,20 @@ def rwkv6_scan(
     if _BACKEND == "ref":
         return ref.rwkv6_scan(r, k, v, w, u, state)
     B, H, S, hd = r.shape
-    chunk = S if S <= 128 else 128
-    if S % chunk:
-        return ref.rwkv6_scan(r, k, v, w, u, state)
+    chunk = min(S, 128)
+    pad = -S % chunk
+    if pad:
+        # padded steps carry k = v = 0 and decay w = 1: they leave the state
+        # as it was, and their outputs are cut off below
+        def tail(a, value):
+            return jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)),
+                           constant_values=value)
+
+        r, k, v, w = tail(r, 0), tail(k, 0), tail(v, 0), tail(w, 1)
     s0 = state if state is not None else jnp.zeros((B, H, hd, hd), jnp.float32)
     fn = _ref_vjp(
         lambda *a: rwkv6_scan_pallas(*a, chunk=chunk, interpret=_INTERPRET),
         lambda *a: ref.rwkv6_scan(*a),
     )
-    return fn(r, k, v, w, u, s0)
+    y, s_out = fn(r, k, v, w, u, s0)
+    return y[:, :, :S], s_out

@@ -19,13 +19,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
-
 __all__ = ["rwkv6_scan_pallas"]
 
 
-def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, state_scr,
-                 *, chunk: int):
+def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
+                 state_scr, r_scr, k_scr, v_scr, w_scr, y_scr, *, chunk: int):
     c = pl.program_id(1)
     nc = pl.num_programs(1)
 
@@ -33,25 +31,30 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, state
     def _init():
         state_scr[...] = s0_ref[0].astype(jnp.float32)
 
-    r = r_ref[0].astype(jnp.float32)  # (chunk, hd)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)  # (hd,)
+    # stage the chunk in f32 VMEM: each time step loads its (1, hd) rows
+    # through the ref (the TPU lowering has no dynamic slice of a value)
+    for src, dst in ((r_ref, r_scr), (k_ref, k_scr), (v_ref, v_scr),
+                     (w_ref, w_scr)):
+        dst[...] = src[0].astype(jnp.float32)
+    hd = state_scr.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1)
+           ).astype(jnp.float32)
 
-    def step(t, carry):
-        s, y = carry
-        rt, kt, vt, wt = r[t], k[t], v[t], w[t]  # (hd,)
-        kv = kt[:, None] * vt[None, :]  # (hd, hd)
-        yt = jnp.sum(rt[:, None] * (s + u[:, None] * kv), axis=0)  # (hd,)
-        y = y.at[t].set(yt)
-        s = wt[:, None] * s + kv
-        return s, y
+    def col(row):  # (1, hd) -> (hd, 1), exact, by a lane reduction
+        return jnp.sum(eye * row, axis=1, keepdims=True)
 
-    y0 = jnp.zeros_like(r)
-    s_final, y = jax.lax.fori_loop(0, chunk, step, (state_scr[...], y0))
-    state_scr[...] = s_final
-    y_ref[0] = y.astype(y_ref.dtype)
+    u = col(u_ref[0].astype(jnp.float32))
+
+    def step(t, s):
+        row = pl.ds(t, 1)
+        kv = col(k_scr[row, :]) * v_scr[row, :]  # (hd, hd) outer product
+        y_scr[row, :] = jnp.sum(col(r_scr[row, :]) * (s + u * kv), axis=0,
+                                keepdims=True)
+        return col(w_scr[row, :]) * s + kv
+
+    state_scr[...] = jax.lax.fori_loop(0, chunk, step, state_scr[...])
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(c == nc - 1)
     def _emit_state():
@@ -77,14 +80,13 @@ def rwkv6_scan_pallas(
     s0 = state if state is not None else jnp.zeros((B, H, hd, hd), jnp.float32)
 
     rf, kf, vf, wf = (a.reshape(B * H, S, hd) for a in (r, k, v, w))
-    uf = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, hd)
+    # (B*H, 1, hd): a (1, 1, hd) block spans the array's last two dims, as
+    # the TPU lowering requires of blocks not (8, 128)-aligned
+    uf = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, 1, hd)
     s0f = s0.reshape(B * H, hd, hd).astype(jnp.float32)
 
     def t_map(b, c):
         return (b, c, 0)
-
-    def b_map(b, c):
-        return (b, 0)
 
     def s_map(b, c):
         return (b, 0, 0)
@@ -97,7 +99,7 @@ def rwkv6_scan_pallas(
             pl.BlockSpec((1, ch, hd), t_map),
             pl.BlockSpec((1, ch, hd), t_map),
             pl.BlockSpec((1, ch, hd), t_map),
-            pl.BlockSpec((1, hd), b_map),
+            pl.BlockSpec((1, 1, hd), s_map),
             pl.BlockSpec((1, hd, hd), s_map),
         ],
         out_specs=[
@@ -108,8 +110,9 @@ def rwkv6_scan_pallas(
             jax.ShapeDtypeStruct((B * H, S, hd), r.dtype),
             jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)]
+        + [pltpu.VMEM((ch, hd), jnp.float32)] * 5,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -42,7 +42,6 @@ from repro.configs import (
     list_archs,
     shape_supported,
 )
-from repro import compat
 from repro.launch.mesh import make_production_mesh
 from repro.models import (
     decode_step,
@@ -313,7 +312,7 @@ def calibrated_costs(
         ov = dict(overrides or {})
         ov.update(num_layers=n, scan_layers=False)
         compiled = _compile_cell(arch, shape_name, mesh, ov)
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         probes[n] = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -361,7 +360,7 @@ def dryrun_cell(
     t_compile = time.perf_counter() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo)
     if hlo_out is not None:

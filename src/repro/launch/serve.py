@@ -40,6 +40,9 @@ from repro.cluster import (ClusterServer, ClusterSim, ReplicaSpec,
 from repro.comms import comm_context
 from repro.compat import make_mesh
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.kernels import ops
+from repro.launch.device import (device_info, place_compile_cache,
+                                 select_kernel_backend)
 from repro.models import init_params
 from repro.runtime import BatchedServer, ServerConfig
 
@@ -65,24 +68,26 @@ def _comms_report(ctx):
                                              sort_keys=True))
 
 
-def _serve_single(args, cfg):
-    params = init_params(jax.random.key(0), cfg)
-    server = BatchedServer(cfg, params, ServerConfig(
-        batch_size=args.batch_size, max_seq=args.max_seq,
-        max_new_tokens=args.new_tokens))
-
+def serve_prompts(server: BatchedServer, prompts):
+    """Drain ``prompts`` through ``server`` inside ONE ``comm_context`` over
+    the local devices (axis ``"tp"``).  Returns the results, the host
+    seconds the drain took, and the context (for its plan report)."""
     mesh = make_mesh((len(jax.devices()),), ("tp",))
     with comm_context(mesh, ("tp",)) as ctx:
-        rng = np.random.default_rng(args.seed)
-        rids = [server.submit(rng.integers(0, cfg.vocab_size,
-                                           size=int(rng.integers(4, 20))))
-                for _ in range(args.requests)]
-        t0 = time.time()
+        for p in prompts:
+            server.submit(p)
+        t0 = time.perf_counter()
         results = server.run_until_drained()
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
+    return results, dt, ctx
+
+
+def report_serving(server: BatchedServer, results, dt: float, ctx) -> None:
     toks = sum(len(v) for v in results.values())
-    print(f"served {len(rids)} requests, {toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s)")
+    print(f"served {len(results)} requests, {toks} tokens in {dt:.2f}s "
+          f"({toks/dt:.1f} tok/s, host clock)")
+    print(f"[serve/kernels] backend={ops.get_backend()} "
+          f"decode attention: {ops.DECODE_ATTENTION_PATH}")
     rep = server.drain_report()
     print(f"[serve/drain] requests={rep['requests']} tokens={rep['tokens']} "
           f"p50={rep['latency_p50_s'] * 1e3:.2f}ms "
@@ -93,6 +98,17 @@ def _serve_single(args, cfg):
               f"gen={r['generated']} queue→prefill→decode→finish "
               f"timestamps recorded")
     _comms_report(ctx)
+
+
+def _serve_single(args, cfg):
+    params = init_params(jax.random.key(0), cfg)
+    server = BatchedServer(cfg, params, ServerConfig(
+        batch_size=args.batch_size, max_seq=args.max_seq,
+        max_new_tokens=args.new_tokens))
+    rng = np.random.default_rng(args.seed)
+    prompts = [rng.integers(0, cfg.vocab_size, size=int(rng.integers(4, 20)))
+               for _ in range(args.requests)]
+    report_serving(server, *serve_prompts(server, prompts))
 
 
 def _serve_cluster(args, cfg):
@@ -172,6 +188,10 @@ def main():
                     help="layer multiplier for deep replicas under --hetero")
     args = ap.parse_args()
 
+    cache = place_compile_cache()
+    backend = select_kernel_backend()
+    print(f"[serve/device] {device_info()} kernels={backend} "
+          f"compile_cache={cache}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(reduce_cfg(cfg), dtype="float32")

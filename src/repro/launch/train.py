@@ -30,6 +30,8 @@ from repro.configs import (
     reduced as reduce_cfg,
 )
 from repro.data import DataConfig, SyntheticLMPipeline
+from repro.launch.device import (device_info, place_compile_cache,
+                                 select_kernel_backend)
 from repro.models import init_params, loss_fn
 from repro.models import sharding as shd
 from repro.optim import OptimizerConfig, adamw_init, adamw_update, opt_state_specs
@@ -149,6 +151,7 @@ def main():
                          "Explicit is the pure-DP path (model axis must be 1).")
     args = ap.parse_args()
 
+    cache = place_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -166,9 +169,11 @@ def main():
     dims = tuple(int(x) for x in args.mesh.split(","))
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
     mesh = compat.make_mesh(dims, names)
-    print(f"mesh={dict(mesh.shape)} devices={len(jax.devices())}")
-
     explicit = args.zero1 == "explicit"
+    backend = select_kernel_backend(None if explicit else mesh)
+    print(f"mesh={dict(mesh.shape)} device={device_info()} "
+          f"kernels={backend} compile_cache={cache}")
+
     if explicit and mesh.shape.get("model", 1) != 1:
         raise SystemExit("--zero1 explicit is the pure-DP shard_map path; "
                          "use a mesh with model axis 1")

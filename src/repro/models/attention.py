@@ -44,6 +44,7 @@ def attention_heads(
     positions: jax.Array,  # (B, S) absolute positions
     kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B,Hkv,T,hd) x2
     cache_pos: Optional[jax.Array] = None,  # () position being written
+    cache_lanes: Optional[jax.Array] = None,  # (B,) bool lanes to write
     qkv: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     """Everything up to (but not including) the output projection: QKV,
@@ -54,6 +55,10 @@ def attention_heads(
     ``qkv`` optionally supplies precomputed (pre-reshape) projections —
     the SP path computes them fused with the sequence all-gather
     (``api.allgather_matmul``) and hands them in here.
+
+    ``cache_lanes`` limits the cache write to those batch lanes; the other
+    lanes keep what the cache holds at ``cache_pos`` (a select over the
+    written block only, not over the cache).
     """
     B, S, _ = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -80,8 +85,14 @@ def attention_heads(
         new_cache = None
     else:
         ck, cv = kv_cache  # (B, Hkv, T, hd)
-        ck = jax.lax.dynamic_update_slice(ck, kh.astype(ck.dtype), (0, 0, cache_pos, 0))
-        cv = jax.lax.dynamic_update_slice(cv, vh.astype(cv.dtype), (0, 0, cache_pos, 0))
+        at = (0, 0, cache_pos, 0)
+        wk, wv = kh.astype(ck.dtype), vh.astype(cv.dtype)
+        if cache_lanes is not None:
+            keep = cache_lanes[:, None, None, None]
+            wk = jnp.where(keep, wk, jax.lax.dynamic_slice(ck, at, wk.shape))
+            wv = jnp.where(keep, wv, jax.lax.dynamic_slice(cv, at, wv.shape))
+        ck = jax.lax.dynamic_update_slice(ck, wk, at)
+        cv = jax.lax.dynamic_update_slice(cv, wv, at)
         new_cache = (ck, cv)
         if S > 1:
             # prefill: the new block is the whole context — attend causally
@@ -106,9 +117,11 @@ def attention(
     positions: jax.Array,  # (B, S) absolute positions
     kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B,Hkv,T,hd) x2
     cache_pos: Optional[jax.Array] = None,  # () position being written
+    cache_lanes: Optional[jax.Array] = None,  # (B,) bool lanes to write
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     out, new_cache = attention_heads(
-        p, cfg, x, positions=positions, kv_cache=kv_cache, cache_pos=cache_pos
+        p, cfg, x, positions=positions, kv_cache=kv_cache, cache_pos=cache_pos,
+        cache_lanes=cache_lanes,
     )
     return dense(p["wo"], out), new_cache
 

@@ -29,7 +29,7 @@ from .attention import (
     attention_tp_out_sp,
     attn_init,
 )
-from .layers import dense, rmsnorm, rmsnorm_init
+from .layers import rmsnorm, rmsnorm_init
 from .mamba2 import mamba2_block, mamba2_init, mamba2_state_init
 from .mlp import ffn_apply, ffn_apply_tp, ffn_apply_tp_sp, mlp, mlp_init
 from .moe import moe_block, moe_init
@@ -155,10 +155,22 @@ def _embed_inputs(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:
     return x
 
 
-def _attn_layer_body(cfg, layer, x, positions, kv, cache_pos):
+def _keep_lanes(lanes, old, new):
+    """``new`` on the batch lanes set in ``lanes`` ((B,) bool, or None for
+    all), ``old`` elsewhere; batch is every leaf's leading dim."""
+    if lanes is None:
+        return new
+    return jax.tree.map(
+        lambda o, n: jnp.where(lanes.reshape((-1,) + (1,) * (n.ndim - 1)),
+                               n, o), old, new)
+
+
+def _attn_layer_body(cfg, layer, x, positions, kv, cache_pos,
+                     cache_lanes=None):
     h, new_kv = attention(
         layer["attn"], cfg, rmsnorm(layer["ln1"], x, cfg.norm_eps),
         positions=positions, kv_cache=kv, cache_pos=cache_pos,
+        cache_lanes=cache_lanes,
     )
     x = x + h
     aux = ZERO_AUX()
@@ -201,12 +213,14 @@ def _scan_or_loop(body, carry, xs, length: int, use_scan: bool):
 
 
 def apply_head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
-    """Final-norm'd hidden -> (padded-)vocab logits in f32."""
+    """Final-norm'd hidden -> (padded-)vocab logits in f32 (accumulated and
+    returned in f32: bf16 logits would round away the gaps greedy decoding
+    and the loss depend on)."""
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = dense(params["lm_head"], x)
-    return logits.astype(jnp.float32)
+        return jnp.einsum("bsd,vd->bsv", x, params["embed"],
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"]["w"],
+                      preferred_element_type=jnp.float32)
 
 
 def forward(
@@ -216,9 +230,14 @@ def forward(
     *,
     cache: Optional[Dict] = None,
     cache_pos: Optional[jax.Array] = None,
+    cache_lanes: Optional[jax.Array] = None,
     head_mode: str = "full",  # 'full' | 'last' | 'none'
 ) -> Tuple[jax.Array, Optional[Dict], Dict]:
-    """Returns (logits-or-hidden, new_cache (if cache given), aux losses)."""
+    """Returns (logits-or-hidden, new_cache (if cache given), aux losses).
+
+    ``cache_lanes`` ((B,) bool) limits the cache update to those batch
+    lanes: the others keep their KV and recurrent state unchanged (a server
+    whose slots sit at different positions steps one position at a time)."""
     x = _embed_inputs(cfg, params, batch)
     x = constrain(x, "hidden")
     B, S, _ = x.shape
@@ -239,6 +258,8 @@ def forward(
             else:
                 layer, st = layer_and_st, None
             h, new_st = _rwkv_layer_body(cfg, layer, h, st)
+            if use_cache:
+                new_st = _keep_lanes(cache_lanes, st, new_st)
             return (h, aux_acc), (new_st if use_cache else 0)
 
         if cfg.remat:
@@ -263,6 +284,8 @@ def forward(
                 layer["mamba"], cfg, rmsnorm(layer["ln1"], h, cfg.norm_eps),
                 state=st if use_cache else None,
             )
+            if use_cache:
+                new_st = _keep_lanes(cache_lanes, st, new_st)
             h = h + m
 
             def run_shared(h, sk, sv):
@@ -277,6 +300,7 @@ def forward(
                 a, new_kv = attention(
                     shared["attn"], cfg, rmsnorm(shared["ln1"], h, cfg.norm_eps),
                     positions=positions, kv_cache=kv, cache_pos=pos0,
+                    cache_lanes=cache_lanes,
                 )
                 h2 = h + a
                 h2 = h2 + mlp(shared["ffn"], cfg,
@@ -316,7 +340,8 @@ def forward(
             h, aux_acc = carry
             layer, kv = xs
             h, new_kv, aux = _attn_layer_body(
-                cfg, layer, h, positions, kv if use_cache else None, pos0
+                cfg, layer, h, positions, kv if use_cache else None, pos0,
+                cache_lanes,
             )
             aux_acc = jax.tree.map(lambda a, b: a + b, aux_acc, aux)
             return (h, aux_acc), (new_kv if use_cache else 0)
@@ -420,10 +445,12 @@ def decode_step(
     state: Dict,
     tokens: jax.Array,  # (B, 1)
     cache_pos: jax.Array,  # ()
+    cache_lanes: Optional[jax.Array] = None,  # (B,) bool lanes to update
 ) -> Tuple[jax.Array, Dict]:
     """One token of autoregressive decode against the serve state."""
     logits, new_cache, _ = forward(
-        cfg, params, {"tokens": tokens}, cache=state, cache_pos=cache_pos
+        cfg, params, {"tokens": tokens}, cache=state, cache_pos=cache_pos,
+        cache_lanes=cache_lanes,
     )
     return logits[:, -1], new_cache
 
@@ -479,11 +506,10 @@ def transformer_block_tp(
     per-call comms plumbing.
     """
     from ..comms import api
-    from ..compat import axis_size
 
     c = ctx if ctx is not None else api.current_context()
     names = c._names(None)
-    n = math.prod(axis_size(a) for a in names)
+    n = math.prod(jax.lax.axis_size(a) for a in names)
     lcfg = _tp_local_cfg(cfg, n)
     ap = layer["attn"]
 

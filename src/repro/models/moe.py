@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..configs.base import ModelConfig
 from .layers import dense, dense_init
 from .mlp import ffn_apply, ffn_init
@@ -33,7 +32,7 @@ def _ep_active(axis_name: str) -> bool:
     """True when ``axis_name`` is bound in the ambient axis env — i.e. we
     are tracing inside a shard_map body that carries the expert axis."""
     try:
-        axis_size(axis_name)
+        lax.axis_size(axis_name)
         return True
     except Exception:
         return False
@@ -91,7 +90,7 @@ def _ep_expert_ffn(experts: Dict, buf: jax.Array, axis_name: str) -> jax.Array:
     (E/m, ...) shard."""
     from ..comms import api  # lazy: models must stay importable without comms
 
-    m = axis_size(axis_name)
+    m = lax.axis_size(axis_name)
     G, E, C, d = buf.shape
     if E % m:
         raise ValueError(

@@ -17,13 +17,12 @@ import jax
 from jax import lax
 
 from ..comms import api
-from ..compat import axis_size
 
 __all__ = ["zero1_shard_grads", "zero1_unshard_params"]
 
 
 def _dp_size(fast_axes: Sequence[str]) -> int:
-    return math.prod(axis_size(n) for n in fast_axes)
+    return math.prod(lax.axis_size(n) for n in fast_axes)
 
 
 def zero1_shard_grads(

@@ -6,9 +6,11 @@ finished slots (EOS or max_new_tokens) are immediately refilled from the
 queue — the standard continuous-batching pattern (vLLM-style, cache-slot
 granularity) built on ``models.decode_step``.
 
-Prefill is per-request against the slot's cache region (cache layouts are
-batched, so prefill runs with batch=1 padding-free and writes into the
-slot's lane via index update).
+Prefill is per-request: it runs over the whole slot batch with the prompt
+in the slot's lane, and only that lane of the new cache is kept.  Decode
+runs one step per distinct slot position, and each step keeps its new
+cache only on the lanes of the slots at that position — the other lanes
+sit at other positions, and a write at this one would corrupt them.
 
 Every request carries a :class:`RequestTiming` record (enqueue /
 prefill-start / prefill-done / decode-start / finish, on the server's
@@ -40,6 +42,9 @@ class ServerConfig:
     max_seq: int = 128
     max_new_tokens: int = 16
     eos_id: int = -1  # -1: disabled (synthetic vocab has no real EOS)
+    # keep the float32 logits row of every emitted token, per request
+    # (BatchedServer.logits) — for checks against a reference forward
+    keep_logits: bool = False
 
 
 @dataclass
@@ -107,18 +112,24 @@ class BatchedServer:
         self.queue: collections.deque = collections.deque()
         self.results: Dict[int, List[int]] = {}
         self.records: Dict[int, RequestTiming] = {}
+        self.logits: Dict[int, List[np.ndarray]] = {}
         self._next_id = 0
         self._tokens = np.zeros((scfg.batch_size, 1), np.int32)
 
-        self._decode = jax.jit(
-            lambda p, s, t, pos: decode_step(cfg, p, s, t, pos)
-        )
-        # one cached jit for prefill too — a fresh lambda per request would
-        # recompile every prefill (retraces only per distinct prompt length)
-        self._prefill = jax.jit(
-            lambda p, b, c: forward(cfg, p, b, cache=c,
-                                    cache_pos=jnp.zeros((), jnp.int32))
-        )
+        def decode(p, state, tokens, pos, lanes):
+            return decode_step(cfg, p, state, tokens, pos, cache_lanes=lanes)
+
+        def prefill(p, batch, state, lanes):
+            logits, new, _ = forward(cfg, p, batch, cache=state,
+                                     cache_pos=jnp.zeros((), jnp.int32),
+                                     cache_lanes=lanes)
+            return logits, new
+
+        # one cached jit each — a fresh lambda per request would recompile
+        # every prefill (it retraces only per distinct prompt length); the
+        # old state is donated, since every call replaces it
+        self._decode = jax.jit(decode, donate_argnums=1)
+        self._prefill = jax.jit(prefill, donate_argnums=2)
 
     # ---- API -------------------------------------------------------------
     def submit(self, prompt: np.ndarray) -> int:
@@ -144,6 +155,7 @@ class BatchedServer:
         self.queue.clear()
         self.results.clear()
         self.records.clear()
+        self.logits.clear()
         self._next_id = 0
         self.slots = [_Slot() for _ in range(self.scfg.batch_size)]
         self.state = init_decode_state(
@@ -157,6 +169,11 @@ class BatchedServer:
     def pending_work(self) -> bool:
         return bool(self.queue) or self.active_count() > 0
 
+    def _lanes(self, idxs: List[int]) -> jax.Array:
+        lanes = np.zeros((self.scfg.batch_size,), bool)
+        lanes[idxs] = True
+        return jnp.asarray(lanes)
+
     def _prefill_into_slot(self, slot_idx: int, rid: int, prompt: np.ndarray):
         """Run the prompt through the model writing KV/state for this slot."""
         rec = self.records[rid]
@@ -167,10 +184,13 @@ class BatchedServer:
         # optimization on real hardware)
         toks = np.zeros((self.scfg.batch_size, S), np.int32)
         toks[slot_idx] = prompt
-        logits, new_state, _ = self._prefill(
-            self.params, {"tokens": jnp.asarray(toks)}, self.state)
-        self.state = self._merge_slot(self.state, new_state, slot_idx)
+        logits, self.state = self._prefill(
+            self.params, {"tokens": jnp.asarray(toks)}, self.state,
+            self._lanes([slot_idx]))
         nxt = int(jnp.argmax(logits[slot_idx, -1]))
+        if self.scfg.keep_logits:
+            self.logits.setdefault(rid, []).append(
+                np.asarray(logits[slot_idx, -1], np.float32))
         slot = self.slots[slot_idx]
         slot.request_id = rid
         slot.pos = S
@@ -189,20 +209,6 @@ class BatchedServer:
         self.results[slot.request_id] = slot.generated
         self.slots[slot_idx] = _Slot()
 
-    def _merge_slot(self, old, new, slot_idx: int):
-        """Keep `new` only on the batch lane of this slot."""
-
-        def pick(o, n):
-            # batch dim differs per cache family; all our caches have the
-            # batch dim right after the layer dim
-            if o.ndim < 2 or o.shape != n.shape:
-                return n
-            sel = jnp.zeros((o.shape[1],), bool).at[slot_idx].set(True)
-            shape = [1, o.shape[1]] + [1] * (o.ndim - 2)
-            return jnp.where(sel.reshape(shape), n, o)
-
-        return jax.tree.map(pick, old, new)
-
     def _refill(self):
         for i, slot in enumerate(self.slots):
             if slot.request_id is None and self.queue:
@@ -214,10 +220,8 @@ class BatchedServer:
         active = [i for i, s in enumerate(self.slots) if s.request_id is not None]
         if not active:
             return
-        # all active slots decode at their own position; the cache mask uses
-        # per-slot positions — we step them at the max position and rely on
-        # each slot's own `pos` for emission bookkeeping (positions differ:
-        # run per-distinct-position micro-batches)
+        # slots sit at different positions: one decode step per distinct
+        # position, each keeping its new cache only on that position's lanes
         by_pos: Dict[int, List[int]] = {}
         for i in active:
             by_pos.setdefault(self.slots[i].pos, []).append(i)
@@ -225,16 +229,19 @@ class BatchedServer:
             step_start = self.clock()
             logits, self.state = self._decode(
                 self.params, self.state, jnp.asarray(self._tokens),
-                jnp.asarray(pos, jnp.int32),
+                jnp.asarray(pos, jnp.int32), self._lanes(idxs),
             )
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            now = self.clock()
+            rows = (np.asarray(logits, np.float32) if self.scfg.keep_logits
+                    else None)
             for i in idxs:
                 slot = self.slots[i]
                 rec = self.records[slot.request_id]
                 if rec.decode_start_s is None:
                     rec.decode_start_s = step_start
                 tok = int(nxt[i])
+                if rows is not None:
+                    self.logits.setdefault(slot.request_id, []).append(rows[i])
                 slot.generated.append(tok)
                 slot.pos += 1
                 self._tokens[i, 0] = tok

@@ -12,7 +12,7 @@ import dataclasses
 
 import jax
 
-from repro.compat import cost_analysis, make_mesh
+from repro.compat import make_mesh
 from repro.configs import get_config, reduced
 from repro.launch.dryrun import build_cell, collective_bytes_from_hlo
 
@@ -40,7 +40,7 @@ for arch, shape in CELLS:
     fn, args, ins, outs, meta = build_cell(arch, shape, mesh, overrides=overrides)
     with mesh:
         compiled = jax.jit(fn, in_shardings=ins, out_shardings=outs).lower(*args).compile()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes_from_hlo(compiled.as_text())
     assert cost.get("flops", 0) > 0, (arch, shape, "no flops")
     print(f"ok {arch} x {shape}: flops={cost.get('flops'):.3e} "

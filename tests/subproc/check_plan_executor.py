@@ -193,14 +193,29 @@ with comm_context(mesh, names):
     check("api mm_rs rank3", api_mmrs(h3, w3r, axis=1), h3 @ w3r, exact=True)
 
 # ---- explicit-TP transformer block vs the GSPMD block (ISSUE 4) -----------
-# Bit-exactness construction: x entries are ±1 (token rms is exactly 1, so
-# rmsnorm is exact), positions are 0 (RoPE multiplies by cos0=1/sin0=0 —
-# identity), and the row-parallel weights (wo, down) are zero outside shard
-# 0's rows — every cross-shard reduction sums exact 0.0s onto shard 0's
-# partial, so ANY reduction order (staged AR, fused RS ring, GSPMD psum,
-# the reference's full-width matmul) produces the same bits.  A second pass
-# with fully dense weights checks all-shards-contributing semantics at
-# float tolerance.
+# Two weight constructions: x entries are ±1, positions are 0 (RoPE is the
+# identity), and either the row-parallel weights (wo, down) are zero outside
+# shard 0's rows — every cross-shard reduction adds exact 0.0s — or they
+# are fully dense.  Neither is compared bit for bit: XLA (0.9) fuses the
+# block differently when it is partitioned, so even its own GSPMD
+# partitioning of the reference block differs from the unpartitioned block
+# in the last float32 bits (-149.72191 vs -149.7219).  The block is
+# compared as float32 arithmetic in another order: the largest difference
+# must stay within SCALED_RTOL of the largest activation.  Integer weights
+# drive activations to ~1e3, where the observed differences are ~1e-3
+# (dense) and ~1e-5 (shard-0 rows); a wrong head split, combine or layout
+# moves outputs by the activations' own size.
+SCALED_RTOL = 1e-5
+
+
+def check_scaled(name, got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    ok = got.shape == want.shape and (
+        np.max(np.abs(got - want)) <= SCALED_RTOL * np.max(np.abs(want)))
+    checks.append((name, ok))
+    if not ok:
+        print(f"FAIL {name}: shapes {got.shape} vs {want.shape}, max diff "
+              f"{np.max(np.abs(got - want))} vs scale {np.max(np.abs(want))}")
 import dataclasses
 
 from repro.comms.api import comm_context
@@ -257,7 +272,7 @@ pos0 = jnp.zeros((B, ST), jnp.int32)
 mesh_tp = make_factorized_mesh([2, 4], ["ta", "tb"])
 names_tp = ("ta", "tb")
 
-for shard0, tag, exact in ((True, "bitexact", True), (False, "dense", False)):
+for shard0, tag in ((True, "shard0-rows"), (False, "dense")):
     layer_tp = int_weights(layer0, shard0_rows=shard0)
     ref = jax.jit(lambda lx, ll: transformer_block_ref(
         ll, cfg_tp, lx, positions=pos0))(x_pm1, layer_tp)
@@ -270,14 +285,9 @@ for shard0, tag, exact in ((True, "bitexact", True), (False, "dense", False)):
                     ll, cfg_tp, lx, positions=pos0, sequence_parallel=sp),
                 mesh=mesh_tp, in_specs=(x_spec, l_spec), out_specs=x_spec,
             ))
-            got = fn(x_pm1, layer_tp)
-            # dense pass: integer weights drive activations to ~1e3, so the
-            # reduction-order differences show up at ~1e-4 absolute — a
-            # semantic (allclose) check, the bit-level contract is above
-            check(f"tp_block {tag} sp={sp}", got, ref,
-                  exact=exact, atol=0.0 if exact else 5e-3)
+            check_scaled(f"tp_block {tag} sp={sp}", fn(x_pm1, layer_tp), ref)
         # the GSPMD path proper: jit partitions the reference block from
-        # TP shardings; with the bit-exact construction it matches too
+        # TP shardings
         if shard0:
             from jax.sharding import NamedSharding
 
@@ -291,8 +301,8 @@ for shard0, tag, exact in ((True, "bitexact", True), (False, "dense", False)):
                 ),
                 out_shardings=NamedSharding(mesh_tp, x_spec),
             )
-            check("tp_block gspmd-partitioned bitexact",
-                  gspmd(x_pm1, layer_tp), ref, exact=True)
+            check_scaled("tp_block gspmd-partitioned", gspmd(x_pm1, layer_tp),
+                     ref)
     assert ctx_tp.cache_stats.misses > 0  # the block planned via the context
 
 # ---- ISSUE 5: optical stage-order search + hybrid execution ---------------
@@ -423,7 +433,13 @@ with comm_context(mesh_ep, ("ep",)) as ctx_ep:
     got_moe = jax.jit(shard_map(
         lambda pp, xx: moe_block(pp, cfg_ep, xx)[0], mesh=mesh_ep,
         in_specs=(P(), P("ep")), out_specs=P("ep")))(p_moe, x_moe)
-    check("moe ep == local reference", got_moe, ref_moe, exact=True)
+    # the a2a moves tokens exactly, but under XLA 0.9 the EP block's expert
+    # matmuls (over (E/8)-expert buffers) round differently from the local
+    # block's in the last float32 bit: bit-exact comparison fails at the
+    # parent tree too, with max diff 1.16e-10 against a largest output of
+    # 1.07e-3 (1.1e-7 of scale; 8 CPU devices, eager, jitted and
+    # shard_map'd local references alike).  Same rule as the TP block.
+    check_scaled("moe ep == local reference", got_moe, ref_moe)
     checks.append(("moe ep issued a2a plans",
                    any(pl.collective == "a2a" for pl in ctx_ep.plans())
                    and ctx_ep.cache_stats.hits > 0))

@@ -287,6 +287,53 @@ class TestServerTimestamps:
         # same prompt on the reset (zeroed-state) server decodes the same
         assert srv.results[rid2] == first
 
+    @pytest.mark.parametrize("family", ["dense", "ssm", "hybrid"])
+    def test_mixed_positions_keep_each_slots_cache(self, family):
+        """A request served beside a shorter prompt keeps the cache and the
+        logits it has when served alone: a decode step at one slot's
+        position keeps its new cache only on that position's lanes.
+        Compares KV (or recurrent state) and logits, never tokens — with
+        random weights greedy decoding repeats one token and would hide a
+        corrupted cache."""
+        import jax
+        import jax.numpy as jnp
+        from repro.configs import get_config, reduced
+        from repro.models import forward, init_params
+        from repro.runtime import BatchedServer, ServerConfig
+
+        arch = {"ssm": "rwkv6-7b", "hybrid": "zamba2-2.7b"}.get(family)
+        cfg = reduced(get_config(arch)) if arch else tiny_cfg()
+        params = init_params(jax.random.key(3), cfg)
+        scfg = ServerConfig(batch_size=2, max_seq=32, max_new_tokens=6,
+                            keep_logits=True)
+        rng = np.random.default_rng(4)
+        long_p = rng.integers(0, cfg.vocab_size, size=9).astype(np.int32)
+        short_p = rng.integers(0, cfg.vocab_size, size=5).astype(np.int32)
+
+        def serve(prompts, steps=3):
+            srv = BatchedServer(cfg, params, scfg)
+            for p in prompts:
+                srv.submit(p)
+            for _ in range(steps):
+                srv.engine_step()
+            lane0 = jax.tree.map(lambda a: np.asarray(a[:, 0]), srv.state)
+            return srv, lane0
+
+        alone, state_alone = serve([long_p])
+        mixed, state_mixed = serve([long_p, short_p])
+        assert mixed.slots[0].pos != mixed.slots[1].pos
+        for a, b in zip(jax.tree.leaves(state_alone),
+                        jax.tree.leaves(state_mixed)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+        got = np.stack(mixed.logits[0])
+        np.testing.assert_allclose(got, np.stack(alone.logits[0]),
+                                   rtol=1e-5, atol=1e-5)
+        # and both equal a no-cache forward over the emitted tokens
+        toks = np.concatenate([long_p, mixed.slots[0].generated[:-1]])
+        ref, _, _ = forward(cfg, params, {"tokens": jnp.asarray(toks[None])})
+        want = np.asarray(ref[0, len(long_p) - 1:])
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
     def test_single_token_request_finishes_at_prefill(self):
         import jax
         from repro.models import init_params

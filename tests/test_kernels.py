@@ -109,12 +109,38 @@ def test_ops_backend_dispatch_and_grad():
         return jnp.sum(ops.rmsnorm(x, scale) ** 2)
 
     g_ref = jax.grad(loss_ref)(x)
-    with ops.backend_scope("pallas"):
+    with ops.backend_scope("pallas", interpret=True):
         assert ops.get_backend() == "pallas"
         g_pal = jax.grad(loss_ref)(x)
         y = ops.swiglu(x, x)
     np.testing.assert_allclose(np.asarray(g_ref), np.asarray(g_pal), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref.swiglu(x, x)), rtol=1e-5, atol=1e-5)
+
+
+def test_interpret_mode_only_on_request():
+    """The Pallas backend compiles its kernels unless a caller asks for
+    interpret mode, and a scope restores both settings on exit."""
+    assert ops.get_backend() == "ref" and ops._INTERPRET is False
+    with ops.backend_scope("pallas", interpret=True):
+        assert ops._INTERPRET is True
+    assert ops.get_backend() == "ref" and ops._INTERPRET is False
+
+
+@pytest.mark.parametrize("S", [1, 150])
+def test_ops_rwkv6_pads_to_chunk(S):
+    """A sequence that is not a multiple of the kernel chunk is padded with
+    state-preserving steps (k = v = 0, w = 1), not routed to the oracle."""
+    B, H, hd = 1, 2, 8
+    r, k, v = (_rand((B, H, S, hd), jnp.float32) * 0.5 for _ in range(3))
+    w = jnp.asarray(RNG.uniform(0.5, 0.95, (B, H, S, hd)), jnp.float32)
+    u = _rand((H, hd), jnp.float32) * 0.1
+    s0 = _rand((B, H, hd, hd), jnp.float32) * 0.1
+    want_y, want_s = ref.rwkv6_scan(r, k, v, w, u, s0)
+    with ops.backend_scope("pallas", interpret=True):
+        got_y, got_s = ops.rwkv6_scan(r, k, v, w, u, s0)
+    assert got_y.shape == (B, H, S, hd)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s), rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("S,chunk,causal,Hkv", [(2048, 512, True, 2), (4096, 1024, True, 4),

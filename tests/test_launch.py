@@ -2,6 +2,7 @@
 enumeration, elastic replanning.  (The heavy lower+compile path is covered
 by tests/test_dryrun_small.py in a subprocess.)"""
 import json
+from pathlib import Path
 
 import pytest
 
@@ -15,6 +16,7 @@ from repro.launch.roofline import (
     roofline_for_cell,
 )
 
+REPO = Path(__file__).resolve().parent.parent
 
 HLO_SAMPLE = """
   %p0 = bf16[4,512,128]{2,1,0} parameter(0)
@@ -205,3 +207,39 @@ class TestArtifacts:
         assert all(c["ok"] for c in cells)
         multien = list(d.glob("*__multipod.json"))
         assert len(multien) == 31
+
+
+class TestChipEntry:
+    """chip_smoke.py refuses to run anywhere but on a TPU, and the entry
+    points' compile cache can be placed from outside."""
+
+    def test_chip_smoke_fails_without_tpu(self):
+        import os
+        import subprocess
+        import sys
+
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        proc = subprocess.run([sys.executable, str(REPO / "chip_smoke.py")],
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode != 0
+        assert "no TPU" in proc.stderr
+        assert '"ok"' not in proc.stdout
+
+    def test_compile_cache_placement(self, monkeypatch, tmp_path):
+        import jax
+
+        from repro.launch import device
+
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            jax.config.update("jax_compilation_cache_dir", None)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert device.place_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir is None  # JAX's own
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            assert device.place_compile_cache() == str(device.CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(device.CACHE_DIR)
+            assert device.CACHE_DIR.parent == REPO
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
