@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.plan_ir import CollectivePlan, PlanStage
+from ..obs import stage_scope
 from .ring_executor import _maybe_inject
 
 __all__ = [
@@ -106,27 +107,32 @@ def _gather_rounds(
     buf: jax.Array,
     groups: Sequence[Tuple[str, int]],
     probe: Optional[Callable],
+    first_stage: int = 0,
 ) -> Tuple[jax.Array, List[Tuple[str, int]]]:
-    """Run every gather group's rounds on ``buf`` (leading-axis block).
+    """Run every gather group's rounds on ``buf`` (leading-axis block);
+    round r is plan stage ``first_stage + r``.
 
     Returns ``(stacked, digits)`` where ``stacked`` has one leading (2,)
     axis per round and ``digits`` labels those axes leading-to-trailing
     (newest round first, since each round stacks a new leading axis).
     """
     digits: List[Tuple[str, int]] = []
+    stage = first_stage
     for name, k in groups:
         idx = lax.axis_index(name)
         before = buf
         for t in range(k):
             sig = k - 1 - t  # MSB first
-            recv = _maybe_inject(
-                lax.ppermute(buf, name, _pair_perm(1 << k, 1 << sig)),
-                name, t + 1)
-            bit = (idx >> sig) & 1
-            # new digit stacks LEADING: slot 0 = the bit-0 half
-            buf = jnp.where(bit == 0, jnp.stack([buf, recv]),
-                            jnp.stack([recv, buf]))
+            with stage_scope(stage, name, "exchange"):
+                recv = _maybe_inject(
+                    lax.ppermute(buf, name, _pair_perm(1 << k, 1 << sig)),
+                    name, t + 1)
+                bit = (idx >> sig) & 1
+                # new digit stacks LEADING: slot 0 = the bit-0 half
+                buf = jnp.where(bit == 0, jnp.stack([buf, recv]),
+                                jnp.stack([recv, buf]))
             digits.insert(0, (name, sig))
+            stage += 1
         if probe is not None:
             probe(before, buf, name)
     return buf, digits
@@ -140,19 +146,22 @@ def _scatter_rounds(
     """Run every scatter group's rounds.  ``buf`` arrives with one leading
     (2,) axis per round in ROUND order (first round's digit leading); each
     round consumes the leading axis — keep my bit's half, swap the other
-    with the partner, add what arrives."""
+    with the partner, add what arrives.  Round r is plan stage r."""
+    stage = 0
     for name, k in groups:
         idx = lax.axis_index(name)
         before = buf
         for t in range(k):
             sig = t  # LSB first: the time-mirror of the gather rounds
-            bit = (idx >> sig) & 1
-            mine = jnp.where(bit == 0, buf[0], buf[1])
-            other = jnp.where(bit == 0, buf[1], buf[0])
-            recv = _maybe_inject(
-                lax.ppermute(other, name, _pair_perm(1 << k, 1 << sig)),
-                name, t + 1)
-            buf = mine + recv
+            with stage_scope(stage, name, "exchange"):
+                bit = (idx >> sig) & 1
+                mine = jnp.where(bit == 0, buf[0], buf[1])
+                other = jnp.where(bit == 0, buf[1], buf[0])
+                recv = _maybe_inject(
+                    lax.ppermute(other, name, _pair_perm(1 << k, 1 << sig)),
+                    name, t + 1)
+                buf = mine + recv
+            stage += 1
         if probe is not None:
             probe(before, buf, name)
     return buf
@@ -250,6 +259,6 @@ def exchange_all_reduce(
     x = jnp.moveaxis(y, axis, 0)
     buf = _split_canonical(x, rs_groups, names)
     block = _scatter_rounds(buf, rs_groups, rs_probe)
-    gathered, digits = _gather_rounds(block, ag_groups, ag_probe)
+    gathered, digits = _gather_rounds(block, ag_groups, ag_probe, k)
     out = _finalize_gather(gathered, digits, names, block.ndim)
     return jnp.moveaxis(out, 0, axis)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.plan_ir import CollectivePlan, PlanStage, effective_stage_mode
+from ..obs import scope
 from .exchange_executor import (
     exchange_all_gather,
     exchange_all_reduce,
@@ -107,7 +108,17 @@ def execute_plan(y: jax.Array, plan: CollectivePlan, *, axis: int = 0,
     :func:`execute_plan_verified` uses for per-stage checksums.  The
     chunked/hybrid wavefronts do not expose stage boundaries; verification
     there happens at collective granularity.
+
+    It runs under ``scope("plan.<collective>")``, each stage's
+    communication under its ``obs.stage_scope``: in a trace, the plan's ops
+    outside every stage scope are its local layout work.
     """
+    with scope(f"plan.{plan.collective}"):
+        return _execute(y, plan, axis, stage_probe)
+
+
+def _execute(y: jax.Array, plan: CollectivePlan, axis: int,
+             stage_probe: Optional[Callable]) -> jax.Array:
     names = plan_axis_names(plan)
     coll = plan.collective
     chunked = plan.mode == "chunked" and plan.num_chunks > 1
@@ -209,7 +220,7 @@ def execute_plan(y: jax.Array, plan: CollectivePlan, *, axis: int = 0,
         return perhop_all_gather(
             y, names, stage_order=tuple(st.axis for st in ag_stages),
             axis=axis, stage_modes=_executor_modes(plan, ag_stages),
-            stage_probe=probe_for("ag"))
+            stage_probe=probe_for("ag"), first_stage=k)
 
     raise ValueError(f"unknown collective {coll!r}")
 

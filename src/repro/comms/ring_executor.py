@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import stage_scope
 from .staged_collectives import (
     _a2a_merge_digits,
     _a2a_split_digits,
@@ -253,6 +254,7 @@ def perhop_all_gather(
     axis: int = 0,
     stage_modes: Optional[Sequence[str]] = None,
     stage_probe: Optional[Callable] = None,
+    first_stage: int = 0,
 ) -> jax.Array:
     """Per-hop staged all-gather inside shard_map: bit-identical to
     ``lax.all_gather(x, tuple(axis_names), axis=axis, tiled=True)``.
@@ -264,7 +266,9 @@ def perhop_all_gather(
 
     ``stage_probe(before, after, name)`` is called once per stage with the
     stage's traced input/output — the hook the verified executor uses for
-    per-stage conservation checksums.
+    per-stage conservation checksums.  Stage ``k`` runs under
+    ``stage_scope(first_stage + k, ...)``: an all-reduce's gather stages
+    follow its scatter stages in the plan.
     """
     axis_names = tuple(axis_names)
     order = (
@@ -277,12 +281,13 @@ def perhop_all_gather(
     if axis < 0:
         axis += x.ndim
     y = x
-    for name, mode in zip(order, modes):
+    for k, (name, mode) in enumerate(zip(order, modes), first_stage):
         before = y
-        if mode == "ring":
-            y = ring_all_gather_stage(y, name)
-        else:
-            y = lax.all_gather(y, name, axis=0, tiled=False)
+        with stage_scope(k, name, mode):
+            if mode == "ring":
+                y = ring_all_gather_stage(y, name)
+            else:
+                y = lax.all_gather(y, name, axis=0, tiled=False)
         if stage_probe is not None:
             stage_probe(before, y, name)
     y = _ag_finalize(y, axis_names, order)  # (N, *x.shape)
@@ -325,25 +330,30 @@ def perhop_reduce_scatter(
         )
     if order != axis_names:
         y = _permute_blocks_to_order(y, axis_names, order, sizes)
-    for name, mode in zip(order, modes):
+    for k, (name, mode) in enumerate(zip(order, modes)):
         before = y
-        if mode == "ring":
-            y = ring_reduce_scatter_stage(y, name)
-        else:
-            y = lax.psum_scatter(y, name, scatter_dimension=0, tiled=True)
+        with stage_scope(k, name, mode):
+            if mode == "ring":
+                y = ring_reduce_scatter_stage(y, name)
+            else:
+                y = lax.psum_scatter(y, name, scatter_dimension=0, tiled=True)
         if stage_probe is not None:
             stage_probe(before, y, name)
     return jnp.moveaxis(y, 0, axis) if axis != 0 else y
 
 
-def _a2a_stage_dispatch(y, name, dim, mode):
-    """One a2a digit transpose on digit axis ``dim``: a double-buffered
-    ppermute rotation ("ring") or the blocking XLA collective ("oneshot")."""
+def _a2a_stage_dispatch(y, name, dim, mode, k):
+    """Stage ``k``, one a2a digit transpose on digit axis ``dim``: a
+    double-buffered ppermute rotation ("ring") or the blocking XLA
+    collective ("oneshot")."""
     if mode == "ring":
         y = jnp.moveaxis(y, dim, 0) if dim != 0 else y
-        y = ring_all_to_all_stage(y, name)
+        with stage_scope(k, name, mode):
+            y = ring_all_to_all_stage(y, name)
         return jnp.moveaxis(y, 0, dim) if dim != 0 else y
-    return lax.all_to_all(y, name, split_axis=dim, concat_axis=dim, tiled=True)
+    with stage_scope(k, name, mode):
+        return lax.all_to_all(y, name, split_axis=dim, concat_axis=dim,
+                              tiled=True)
 
 
 def perhop_all_to_all(
@@ -379,9 +389,10 @@ def perhop_all_to_all(
         axis += x.ndim
     y = jnp.moveaxis(x, axis, 0) if axis != 0 else x
     shaped = _a2a_split_digits(y, axis_names, sizes)
-    for name, mode in zip(order, modes):
+    for j, (name, mode) in enumerate(zip(order, modes)):
         before = shaped
-        shaped = _a2a_stage_dispatch(shaped, name, axis_names.index(name), mode)
+        shaped = _a2a_stage_dispatch(shaped, name, axis_names.index(name),
+                                     mode, j)
         if stage_probe is not None:
             stage_probe(before, shaped, name)
     out = _a2a_merge_digits(shaped, k)
@@ -404,16 +415,18 @@ def perhop_all_to_all(
 # form; ring RS reduces in ring order — exact for exactly-representable
 # sums).
 
-def _hyb_ag_stage(ch: jax.Array, name: str, mode: str) -> jax.Array:
-    if mode == "ring":
-        return ring_all_gather_stage(ch, name)
-    return lax.all_gather(ch, name, axis=0, tiled=False)
+def _hyb_ag_stage(ch: jax.Array, name: str, mode: str, k: int) -> jax.Array:
+    with stage_scope(k, name, mode):
+        if mode == "ring":
+            return ring_all_gather_stage(ch, name)
+        return lax.all_gather(ch, name, axis=0, tiled=False)
 
 
-def _hyb_rs_stage(ch: jax.Array, name: str, mode: str) -> jax.Array:
-    if mode == "ring":
-        return ring_reduce_scatter_stage(ch, name)
-    return lax.psum_scatter(ch, name, scatter_dimension=0, tiled=True)
+def _hyb_rs_stage(ch: jax.Array, name: str, mode: str, k: int) -> jax.Array:
+    with stage_scope(k, name, mode):
+        if mode == "ring":
+            return ring_reduce_scatter_stage(ch, name)
+        return lax.psum_scatter(ch, name, scatter_dimension=0, tiled=True)
 
 
 def hybrid_all_gather(
@@ -447,7 +460,7 @@ def hybrid_all_gather(
     chunks = [y[c * per_chunk:(c + 1) * per_chunk] for c in range(num_chunks)]
     chunks = _wavefront(
         chunks, len(order),
-        lambda ch, j: _hyb_ag_stage(ch, order[j], modes[j]),
+        lambda ch, j: _hyb_ag_stage(ch, order[j], modes[j], j),
     )
     gathered = [_ag_finalize(ch, axis_names, order) for ch in chunks]
     out = jnp.stack(gathered, axis=1)  # (N, C, per_chunk, ...)
@@ -483,7 +496,7 @@ def hybrid_reduce_scatter(
     chunks = _split_rs_chunks(y, axis_names, order, sizes, num_chunks)
     chunks = _wavefront(
         chunks, len(order),
-        lambda ch, j: _hyb_rs_stage(ch, order[j], modes[j]),
+        lambda ch, j: _hyb_rs_stage(ch, order[j], modes[j], j),
     )
     out = chunks[0] if num_chunks == 1 else jnp.concatenate(chunks, axis=0)
     return jnp.moveaxis(out, 0, axis) if axis != 0 else out
@@ -530,7 +543,7 @@ def hybrid_all_to_all(
     chunks = _wavefront(
         chunks, k,
         lambda ch, j: _a2a_stage_dispatch(
-            ch, order[j], axis_names.index(order[j]), modes[j]),
+            ch, order[j], axis_names.index(order[j]), modes[j], j),
     )
     out = chunks[0] if num_chunks == 1 else jnp.concatenate(chunks, axis=k)
     out = _a2a_merge_digits(out, k)
@@ -570,8 +583,8 @@ def hybrid_all_reduce(
 
     def apply_stage(ch, j):
         if j < k:
-            return _hyb_rs_stage(ch, order[j], modes[j])
-        return _hyb_ag_stage(ch, ag_order[j - k], modes[j])
+            return _hyb_rs_stage(ch, order[j], modes[j], j)
+        return _hyb_ag_stage(ch, ag_order[j - k], modes[j], j)
 
     chunks = _wavefront(chunks, 2 * k, apply_stage)
     gathered = [_ag_finalize(ch, axis_names, ag_order) for ch in chunks]
@@ -608,5 +621,5 @@ def perhop_all_reduce(
     )
     return perhop_all_gather(
         y, axis_names, stage_order=tuple(reversed(order)), axis=axis,
-        stage_modes=modes[k:],
+        stage_modes=modes[k:], first_stage=k,
     )

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
 from ..core.planner import ICI_LINK, DCN_LINK, LinkSpec, plan_axis_order
+from ..obs import stage_scope
 
 __all__ = ["staged_all_gather", "canonical_all_gather", "optree_all_gather",
            "link_for_axis", "names_for_plan"]
@@ -78,14 +79,16 @@ def staged_all_gather(
     if order == tuple(reversed(axis_names)):
         # minor-to-major: tiled gathers compose to canonical order directly
         y = x
-        for name in order:
-            y = jax.lax.all_gather(y, name, axis=axis, tiled=True)
+        for k, name in enumerate(order):
+            with stage_scope(k, name, "oneshot"):
+                y = jax.lax.all_gather(y, name, axis=axis, tiled=True)
         return y
 
     # general order: stack stages as leading axes, then one local fix-up
     y = x
-    for name in order:
-        y = jax.lax.all_gather(y, name, axis=0, tiled=False)
+    for k, name in enumerate(order):
+        with stage_scope(k, name, "oneshot"):
+            y = jax.lax.all_gather(y, name, axis=0, tiled=False)
     # leading stacked axes are reversed(order); want axis_names order
     stacked = tuple(reversed(order))
     perm_named = tuple(stacked.index(n) for n in axis_names)

@@ -43,6 +43,7 @@ from ..core.planner import (
     plan_axis_order,
     plan_reduce_scatter_order,
 )
+from ..obs import stage_scope
 from .staged_allgather import link_for_axis, names_for_plan, staged_all_gather
 
 __all__ = [
@@ -86,14 +87,16 @@ def _permute_blocks_to_order(y, axis_names, order, sizes):
     return shaped.reshape(y.shape)
 
 
-def _rs_stage(y, name):
-    return lax.psum_scatter(y, name, scatter_dimension=0, tiled=True)
+def _rs_stage(y, name, k):
+    with stage_scope(k, name, "oneshot"):
+        return lax.psum_scatter(y, name, scatter_dimension=0, tiled=True)
 
 
-def _ag_stage(y, name):
+def _ag_stage(y, name, k):
     # stacking form: composes under any stage order; one local fix-up at the
     # end restores canonical device order (cf. staged_all_gather)
-    return lax.all_gather(y, name, axis=0, tiled=False)
+    with stage_scope(k, name, "oneshot"):
+        return lax.all_gather(y, name, axis=0, tiled=False)
 
 
 def _ag_finalize(y, axis_names, order):
@@ -180,7 +183,7 @@ def staged_reduce_scatter(
     y = jnp.moveaxis(x, axis, 0) if axis != 0 else x
     chunks = _split_rs_chunks(y, axis_names, order, sizes, num_chunks)
     chunks = _wavefront(
-        chunks, len(order), lambda ch, j: _rs_stage(ch, order[j])
+        chunks, len(order), lambda ch, j: _rs_stage(ch, order[j], j)
     )
     out = chunks[0] if num_chunks == 1 else jnp.concatenate(chunks, axis=0)
     return jnp.moveaxis(out, 0, axis) if axis != 0 else out
@@ -209,7 +212,7 @@ def staged_all_gather_chunked(
     per_chunk = shard // num_chunks
     chunks = [y[c * per_chunk:(c + 1) * per_chunk] for c in range(num_chunks)]
     chunks = _wavefront(
-        chunks, len(order), lambda ch, j: _ag_stage(ch, order[j])
+        chunks, len(order), lambda ch, j: _ag_stage(ch, order[j], j)
     )
     gathered = [_ag_finalize(ch, axis_names, order) for ch in chunks]
     # interleave: device p's shard is the concat of its chunks
@@ -242,10 +245,12 @@ def _a2a_merge_digits(y, k: int):
     return y.reshape((n_total * y.shape[k],) + y.shape[k + 1:])
 
 
-def _a2a_stage(y, name, dim):
+def _a2a_stage(y, name, dim, k):
     # one digit transpose: exchange the m slices along digit axis ``dim``
     # over sub-axis ``name`` (out[d] = device d's slice for us)
-    return lax.all_to_all(y, name, split_axis=dim, concat_axis=dim, tiled=True)
+    with stage_scope(k, name, "oneshot"):
+        return lax.all_to_all(y, name, split_axis=dim, concat_axis=dim,
+                              tiled=True)
 
 
 def staged_all_to_all(
@@ -292,7 +297,8 @@ def staged_all_to_all(
     ]
     chunks = _wavefront(
         chunks, k,
-        lambda ch, j: _a2a_stage(ch, order[j], axis_names.index(order[j])),
+        lambda ch, j: _a2a_stage(
+            ch, order[j], axis_names.index(order[j]), j),
     )
     out = chunks[0] if num_chunks == 1 else jnp.concatenate(chunks, axis=k)
     out = _a2a_merge_digits(out, k)
@@ -337,8 +343,8 @@ def staged_all_reduce(
 
     def apply_stage(ch, j):
         if j < k:
-            return _rs_stage(ch, order[j])
-        return _ag_stage(ch, ag_order[j - k])
+            return _rs_stage(ch, order[j], j)
+        return _ag_stage(ch, ag_order[j - k], j)
 
     chunks = _wavefront(chunks, 2 * k, apply_stage)
     gathered = [_ag_finalize(ch, axis_names, ag_order) for ch in chunks]

@@ -136,6 +136,7 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     out = out.reshape(B, H, Sp, hd)
     return out[:, :, :S] if pad_q else out

@@ -111,6 +111,7 @@ def mamba2_ssd_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="mamba2_scan",
     )(xf, Bmat, Cmat, dcf, dtf, s0f)
     y = y.reshape(B, H, S, P)
     return jnp.moveaxis(y, 1, 2), sT.reshape(B, H, P, N)

@@ -59,6 +59,7 @@ def rmsnorm_pallas(
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale)
     if pad_rows:
         out = out[:rows]

@@ -116,5 +116,6 @@ def rwkv6_scan_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="rwkv6_scan",
     )(rf, kf, vf, wf, uf, s0f)
     return y.reshape(B, H, S, hd), sT.reshape(B, H, hd, hd)

@@ -54,6 +54,7 @@ def swiglu_pallas(
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(g2.shape, gate.dtype),
         interpret=interpret,
+        name="swiglu",
     )(g2, u2)
     if pad_r or pad_c:
         out = out[:rows, :d]

@@ -26,7 +26,16 @@ def place_compile_cache() -> str:
     """Turn on JAX's persistent compile cache and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
-    nothing is set here; otherwise the cache goes to :data:`CACHE_DIR`."""
+    nothing is set here; otherwise the cache goes to :data:`CACHE_DIR`.
+
+    The cache key includes the programs' metadata: by default JAX leaves it
+    out, so a program whose ``repro.obs`` scopes changed would load the
+    executable of its older source, and a trace would show that source's
+    op names instead of its own stages.  Source files enter the metadata by
+    their base names only, so the key does not depend on where the
+    checkout lies."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex", r".*/")
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

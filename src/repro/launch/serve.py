@@ -93,6 +93,7 @@ def report_serving(server: BatchedServer, results, dt: float, ctx) -> None:
           f"p50={rep['latency_p50_s'] * 1e3:.2f}ms "
           f"p99={rep['latency_p99_s'] * 1e3:.2f}ms "
           f"ttft_p50={rep['ttft_p50_s'] * 1e3:.2f}ms")
+    print(f"[serve/drain] stats={json.dumps(server.stats.to_json())}")
     for r in rep["per_request"]:
         print(f"[serve/drain]   rid={r['rid']} prompt={r['prompt_tokens']} "
               f"gen={r['generated']} queue→prefill→decode→finish "

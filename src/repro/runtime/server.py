@@ -12,6 +12,12 @@ runs one step per distinct slot position, and each step keeps its new
 cache only on the lanes of the slots at that position — the other lanes
 sit at other positions, and a write at this one would corrupt them.
 
+Each engine step, prefill, decode call and token read runs in a host span
+(``repro.obs.span``: ``server.engine_step`` holding ``server.prefill`` and
+``server.decode``, each holding one ``server.fetch``), and
+:class:`ServerStats` counts them; ``docs/serving.md`` says how to trace a
+live server and read both.
+
 Every request carries a :class:`RequestTiming` record (enqueue /
 prefill-start / prefill-done / decode-start / finish, on the server's
 ``clock``), exposed per request in :meth:`BatchedServer.drain_report` —
@@ -22,6 +28,7 @@ compare field-for-field.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
@@ -30,10 +37,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs.base import ModelConfig
 from ..models import decode_step, forward, init_decode_state
 
-__all__ = ["ServerConfig", "BatchedServer", "RequestTiming"]
+__all__ = ["ServerConfig", "BatchedServer", "RequestTiming", "ServerStats"]
 
 
 @dataclass(frozen=True)
@@ -92,6 +100,21 @@ class RequestTiming:
 
 
 @dataclass
+class ServerStats:
+    """Work counters since the server was built or last reset: engine
+    steps, prefill calls, decode calls (one per distinct slot position in
+    a step) and tokens emitted (one per prefill, one per decoded lane)."""
+
+    engine_steps: int = 0
+    prefill_calls: int = 0
+    decode_calls: int = 0
+    tokens: int = 0
+
+    def to_json(self) -> Dict[str, int]:
+        return dataclasses.asdict(self)
+
+
+@dataclass
 class _Slot:
     request_id: Optional[int] = None
     pos: int = 0
@@ -115,6 +138,7 @@ class BatchedServer:
         self.logits: Dict[int, List[np.ndarray]] = {}
         self._next_id = 0
         self._tokens = np.zeros((scfg.batch_size, 1), np.int32)
+        self.stats = ServerStats()
 
         def decode(p, state, tokens, pos, lanes):
             return decode_step(cfg, p, state, tokens, pos, cache_lanes=lanes)
@@ -145,7 +169,8 @@ class BatchedServer:
         """Return the server to its just-constructed state: drain any
         in-flight work (finishing it cleanly rather than abandoning slots
         mid-decode), then clear the queue, results, timing records and the
-        request-id counter, and zero the decode state.  The compiled
+        request-id counter and the :class:`ServerStats`, and zero the
+        decode state.  The compiled
         decode/prefill jits are KEPT — a reset server re-serves warm,
         which is the point of resetting instead of rebuilding (e.g. the
         cluster front end re-running a trace under a different routing
@@ -161,6 +186,7 @@ class BatchedServer:
         self.state = init_decode_state(
             self.cfg, self.scfg.batch_size, self.scfg.max_seq)
         self._tokens = np.zeros((self.scfg.batch_size, 1), np.int32)
+        self.stats = ServerStats()
 
     def active_count(self) -> int:
         """Occupied decode slots (the scheduler's in-flight signal)."""
@@ -182,15 +208,19 @@ class BatchedServer:
         # batch the prompt across the full slot dim (only slot_idx's lanes
         # are kept — simple and correct; per-slot cache views are a perf
         # optimization on real hardware)
-        toks = np.zeros((self.scfg.batch_size, S), np.int32)
-        toks[slot_idx] = prompt
-        logits, self.state = self._prefill(
-            self.params, {"tokens": jnp.asarray(toks)}, self.state,
-            self._lanes([slot_idx]))
-        nxt = int(jnp.argmax(logits[slot_idx, -1]))
-        if self.scfg.keep_logits:
-            self.logits.setdefault(rid, []).append(
-                np.asarray(logits[slot_idx, -1], np.float32))
+        with obs.span("server.prefill", rid=rid, slot=slot_idx, len=S):
+            toks = np.zeros((self.scfg.batch_size, S), np.int32)
+            toks[slot_idx] = prompt
+            logits, self.state = self._prefill(
+                self.params, {"tokens": jnp.asarray(toks)}, self.state,
+                self._lanes([slot_idx]))
+            with obs.span("server.fetch"):
+                nxt = int(jnp.argmax(logits[slot_idx, -1]))
+            if self.scfg.keep_logits:
+                self.logits.setdefault(rid, []).append(
+                    np.asarray(logits[slot_idx, -1], np.float32))
+        self.stats.prefill_calls += 1
+        self.stats.tokens += 1
         slot = self.slots[slot_idx]
         slot.request_id = rid
         slot.pos = S
@@ -216,43 +246,55 @@ class BatchedServer:
                 self._prefill_into_slot(i, rid, prompt)
 
     def engine_step(self):
-        self._refill()
-        active = [i for i, s in enumerate(self.slots) if s.request_id is not None]
-        if not active:
-            return
-        # slots sit at different positions: one decode step per distinct
-        # position, each keeping its new cache only on that position's lanes
-        by_pos: Dict[int, List[int]] = {}
-        for i in active:
-            by_pos.setdefault(self.slots[i].pos, []).append(i)
-        for pos, idxs in sorted(by_pos.items()):
-            step_start = self.clock()
+        self.stats.engine_steps += 1
+        with obs.span("server.engine_step", step=self.stats.engine_steps):
+            self._refill()
+            active = [i for i, s in enumerate(self.slots)
+                      if s.request_id is not None]
+            if not active:
+                return
+            # slots sit at different positions: one decode step per distinct
+            # position, each keeping its new cache only on that position's
+            # lanes
+            by_pos: Dict[int, List[int]] = {}
+            for i in active:
+                by_pos.setdefault(self.slots[i].pos, []).append(i)
+            for pos, idxs in sorted(by_pos.items()):
+                self._decode_at(pos, idxs)
+
+    def _decode_at(self, pos: int, idxs: List[int]):
+        """One decode call for the slots ``idxs``, all at position ``pos``."""
+        step_start = self.clock()
+        with obs.span("server.decode", pos=pos, lanes=len(idxs)):
             logits, self.state = self._decode(
                 self.params, self.state, jnp.asarray(self._tokens),
                 jnp.asarray(pos, jnp.int32), self._lanes(idxs),
             )
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with obs.span("server.fetch"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
             rows = (np.asarray(logits, np.float32) if self.scfg.keep_logits
                     else None)
-            for i in idxs:
-                slot = self.slots[i]
-                rec = self.records[slot.request_id]
-                if rec.decode_start_s is None:
-                    rec.decode_start_s = step_start
-                tok = int(nxt[i])
-                if rows is not None:
-                    self.logits.setdefault(slot.request_id, []).append(rows[i])
-                slot.generated.append(tok)
-                slot.pos += 1
-                self._tokens[i, 0] = tok
-                rec.generated = len(slot.generated)
-                done = (
-                    len(slot.generated) >= self.scfg.max_new_tokens
-                    or tok == self.scfg.eos_id
-                    or slot.pos >= self.scfg.max_seq - 1
-                )
-                if done:
-                    self._finish_slot(i)
+        self.stats.decode_calls += 1
+        self.stats.tokens += len(idxs)
+        for i in idxs:
+            slot = self.slots[i]
+            rec = self.records[slot.request_id]
+            if rec.decode_start_s is None:
+                rec.decode_start_s = step_start
+            tok = int(nxt[i])
+            if rows is not None:
+                self.logits.setdefault(slot.request_id, []).append(rows[i])
+            slot.generated.append(tok)
+            slot.pos += 1
+            self._tokens[i, 0] = tok
+            rec.generated = len(slot.generated)
+            done = (
+                len(slot.generated) >= self.scfg.max_new_tokens
+                or tok == self.scfg.eos_id
+                or slot.pos >= self.scfg.max_seq - 1
+            )
+            if done:
+                self._finish_slot(i)
 
     def run_until_drained(self, max_steps: int = 1000) -> Dict[int, List[int]]:
         steps = 0

@@ -227,19 +227,55 @@ class TestChipEntry:
         assert '"ok"' not in proc.stdout
 
     def test_compile_cache_placement(self, monkeypatch, tmp_path):
+        import importlib.util
+
         import jax
+        import numpy as np
 
         from repro.launch import device
 
         prev = jax.config.jax_compilation_cache_dir
+        key = "jax_compilation_cache_include_metadata_in_key"
+        files = "jax_hlo_source_file_canonicalization_regex"
+        prev_key, prev_files = getattr(jax.config, key), \
+            getattr(jax.config, files)
+        # one function, from the same source in two checkouts
+        fns = []
+        for where in ("one", "two/deeper"):
+            d = tmp_path / where
+            d.mkdir(parents=True)
+            (d / "prog.py").write_text(
+                "import jax\n\n\ndef f(x):\n"
+                "    with jax.named_scope('stage0.x.oneshot'):\n"
+                "        return x * 2\n")
+            spec = importlib.util.spec_from_file_location(
+                f"prog_{len(fns)}", d / "prog.py")
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            fns.append(mod.f)
+
+        def lowered(f):  # what the key hashes: the module with its metadata
+            return jax.jit(f).lower(np.ones(4, np.float32)).as_text(
+                debug_info=True)
+
         try:
             jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update(key, False)
+            jax.config.update(files, None)
             monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
             assert device.place_compile_cache() == str(tmp_path)
             assert jax.config.jax_compilation_cache_dir is None  # JAX's own
+            # scopes are metadata: they must be part of the key
+            assert getattr(jax.config, key) is True
+            # and the checkout's place is not
+            a, b = (lowered(f) for f in fns)
+            assert "stage0.x.oneshot" in a and str(tmp_path) not in a
+            assert a == b
             monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
             assert device.place_compile_cache() == str(device.CACHE_DIR)
             assert jax.config.jax_compilation_cache_dir == str(device.CACHE_DIR)
             assert device.CACHE_DIR.parent == REPO
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
+            jax.config.update(key, prev_key)
+            jax.config.update(files, prev_files)
