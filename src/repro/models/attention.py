@@ -43,7 +43,7 @@ def attention_heads(
     *,
     positions: jax.Array,  # (B, S) absolute positions
     kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B,Hkv,T,hd) x2
-    cache_pos: Optional[jax.Array] = None,  # () position being written
+    cache_pos: Optional[jax.Array] = None,  # () or (B,) position written
     cache_lanes: Optional[jax.Array] = None,  # (B,) bool lanes to write
     qkv: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
@@ -56,9 +56,11 @@ def attention_heads(
     the SP path computes them fused with the sequence all-gather
     (``api.allgather_matmul``) and hands them in here.
 
-    ``cache_lanes`` limits the cache write to those batch lanes; the other
-    lanes keep what the cache holds at ``cache_pos`` (a select over the
-    written block only, not over the cache).
+    ``cache_pos`` is one position for every lane (``()``) or one per lane
+    (``(B,)``: each lane writes its block and masks its keys at its own
+    position).  ``cache_lanes`` limits the cache write to those batch
+    lanes; the other lanes keep what the cache holds at ``cache_pos`` (a
+    select over the written block only, not over the cache).
     """
     B, S, _ = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -85,28 +87,49 @@ def attention_heads(
         new_cache = None
     else:
         ck, cv = kv_cache  # (B, Hkv, T, hd)
-        at = (0, 0, cache_pos, 0)
-        wk, wv = kh.astype(ck.dtype), vh.astype(cv.dtype)
-        if cache_lanes is not None:
-            keep = cache_lanes[:, None, None, None]
-            wk = jnp.where(keep, wk, jax.lax.dynamic_slice(ck, at, wk.shape))
-            wv = jnp.where(keep, wv, jax.lax.dynamic_slice(cv, at, wv.shape))
-        ck = jax.lax.dynamic_update_slice(ck, wk, at)
-        cv = jax.lax.dynamic_update_slice(cv, wv, at)
+        ck = _cache_write(ck, kh.astype(ck.dtype), cache_pos, cache_lanes)
+        cv = _cache_write(cv, vh.astype(cv.dtype), cache_pos, cache_lanes)
         new_cache = (ck, cv)
         if S > 1:
             # prefill: the new block is the whole context — attend causally
             # within it; the cache write above is just state installation
             out = ops.flash_attention(qh, kh, vh, causal=cfg.causal)
         else:
-            # decode: one query against the valid prefix of the cache
+            # decode: one query against the valid prefix of each lane's cache
             T = ck.shape[2]
-            valid = jnp.arange(T)[None, :] <= cache_pos  # (1, T)
+            valid = jnp.arange(T)[None, :] <= jnp.reshape(cache_pos, (-1, 1))
             valid = jnp.broadcast_to(valid, (B, T))
             out = ops.flash_attention(qh, ck, cv, causal=False, kv_mask=valid)
 
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return out, new_cache
+
+
+def _cache_write(c: jax.Array, new: jax.Array, pos: jax.Array,
+                 lanes: Optional[jax.Array]) -> jax.Array:
+    """``new`` (B, Hkv, S, hd) written into the cache ``c`` (B, Hkv, T, hd)
+    at ``pos``: ``()`` for every lane, or ``(B,)`` one per lane.  Lanes not
+    set in ``lanes`` keep what ``c`` holds.
+
+    Every write is one update slice of all lanes at one position, with a
+    select over the written block only (never over the cache): per-lane
+    positions make one such write per lane, each taking its own lane's new
+    rows and the other lanes' rows as they stand.  XLA keeps the cache's
+    layout through these; a scatter of B rows (``vmap`` of the update)
+    moves it and adds a layout copy of the cache in every layer."""
+    if jnp.ndim(pos) == 0:
+        writes = [(pos, lanes)]
+    else:
+        lane = jnp.arange(c.shape[0])
+        writes = [(pos[b], lane == b if lanes is None else (lane == b) & lanes)
+                  for b in range(c.shape[0])]
+    for p, pick in writes:
+        at = (0, 0, p, 0)
+        block = new if pick is None else jnp.where(
+            pick[:, None, None, None], new,
+            jax.lax.dynamic_slice(c, at, new.shape))
+        c = jax.lax.dynamic_update_slice(c, block, at)
+    return c
 
 
 def attention(
@@ -116,7 +139,7 @@ def attention(
     *,
     positions: jax.Array,  # (B, S) absolute positions
     kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B,Hkv,T,hd) x2
-    cache_pos: Optional[jax.Array] = None,  # () position being written
+    cache_pos: Optional[jax.Array] = None,  # () or (B,) position written
     cache_lanes: Optional[jax.Array] = None,  # (B,) bool lanes to write
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     out, new_cache = attention_heads(

@@ -235,14 +235,15 @@ def forward(
 ) -> Tuple[jax.Array, Optional[Dict], Dict]:
     """Returns (logits-or-hidden, new_cache (if cache given), aux losses).
 
-    ``cache_lanes`` ((B,) bool) limits the cache update to those batch
-    lanes: the others keep their KV and recurrent state unchanged (a server
-    whose slots sit at different positions steps one position at a time)."""
+    ``cache_pos`` is ``()`` (every lane starts there) or ``(B,)`` (one
+    start per lane: a server decodes slots at different positions in one
+    call).  ``cache_lanes`` ((B,) bool) limits the cache update to those
+    batch lanes: the others keep their KV and recurrent state unchanged."""
     x = _embed_inputs(cfg, params, batch)
     x = constrain(x, "hidden")
     B, S, _ = x.shape
     pos0 = jnp.zeros((), jnp.int32) if cache_pos is None else cache_pos
-    positions = (pos0 + jnp.arange(S))[None, :].astype(jnp.int32)
+    positions = (jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)).astype(jnp.int32)
     positions = jnp.broadcast_to(positions, (B, S))
     L = cfg.num_layers
 
@@ -444,10 +445,15 @@ def decode_step(
     params: Dict,
     state: Dict,
     tokens: jax.Array,  # (B, 1)
-    cache_pos: jax.Array,  # ()
+    cache_pos: jax.Array,  # () or (B,)
     cache_lanes: Optional[jax.Array] = None,  # (B,) bool lanes to update
 ) -> Tuple[jax.Array, Dict]:
-    """One token of autoregressive decode against the serve state."""
+    """One token of autoregressive decode against the serve state.
+
+    ``cache_pos`` is the position each lane's token takes: ``()`` for all
+    lanes, or ``(B,)``, one per lane, so lanes at different positions
+    decode in one call (RoPE, the cache write and the key mask each at the
+    lane's own position)."""
     logits, new_cache, _ = forward(
         cfg, params, {"tokens": tokens}, cache=state, cache_pos=cache_pos,
         cache_lanes=cache_lanes,

@@ -8,9 +8,9 @@ granularity) built on ``models.decode_step``.
 
 Prefill is per-request: it runs over the whole slot batch with the prompt
 in the slot's lane, and only that lane of the new cache is kept.  Decode
-runs one step per distinct slot position, and each step keeps its new
-cache only on the lanes of the slots at that position — the other lanes
-sit at other positions, and a write at this one would corrupt them.
+is one call per engine step for every active slot, each lane at its own
+cache position (``decode_step`` with a ``(B,)`` position); the lanes of
+empty slots keep their cache.
 
 Each engine step, prefill, decode call and token read runs in a host span
 (``repro.obs.span``: ``server.engine_step`` holding ``server.prefill`` and
@@ -102,8 +102,8 @@ class RequestTiming:
 @dataclass
 class ServerStats:
     """Work counters since the server was built or last reset: engine
-    steps, prefill calls, decode calls (one per distinct slot position in
-    a step) and tokens emitted (one per prefill, one per decoded lane)."""
+    steps, prefill calls, decode calls (one per engine step that decodes)
+    and tokens emitted (one per prefill, one per decoded lane)."""
 
     engine_steps: int = 0
     prefill_calls: int = 0
@@ -251,24 +251,20 @@ class BatchedServer:
             self._refill()
             active = [i for i, s in enumerate(self.slots)
                       if s.request_id is not None]
-            if not active:
-                return
-            # slots sit at different positions: one decode step per distinct
-            # position, each keeping its new cache only on that position's
-            # lanes
-            by_pos: Dict[int, List[int]] = {}
-            for i in active:
-                by_pos.setdefault(self.slots[i].pos, []).append(i)
-            for pos, idxs in sorted(by_pos.items()):
-                self._decode_at(pos, idxs)
+            if active:
+                self._decode_slots(active)
 
-    def _decode_at(self, pos: int, idxs: List[int]):
-        """One decode call for the slots ``idxs``, all at position ``pos``."""
+    def _decode_slots(self, idxs: List[int]):
+        """One decode call for the slots ``idxs``, each at its own cache
+        position; the other lanes pass position 0 and keep their cache."""
         step_start = self.clock()
-        with obs.span("server.decode", pos=pos, lanes=len(idxs)):
+        pos = np.zeros((self.scfg.batch_size,), np.int32)
+        pos[idxs] = [self.slots[i].pos for i in idxs]
+        with obs.span("server.decode", max_pos=int(pos.max()),
+                      lanes=len(idxs)):
             logits, self.state = self._decode(
                 self.params, self.state, jnp.asarray(self._tokens),
-                jnp.asarray(pos, jnp.int32), self._lanes(idxs),
+                jnp.asarray(pos), self._lanes(idxs),
             )
             with obs.span("server.fetch"):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1))

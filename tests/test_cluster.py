@@ -349,6 +349,61 @@ class TestServerTimestamps:
         assert t.finish_s is not None and t.decode_start_s is None
         assert len(srv.results[rid]) == 1
 
+    @staticmethod
+    def _counted_server(batch_size, max_new_tokens):
+        """A tiny server whose decode calls are recorded as
+        ``(engine step, positions, lanes)``."""
+        import jax
+        from repro.models import init_params
+        from repro.runtime import BatchedServer, ServerConfig
+
+        cfg = tiny_cfg()
+        srv = BatchedServer(cfg, init_params(jax.random.key(0), cfg),
+                            ServerConfig(batch_size=batch_size, max_seq=32,
+                                         max_new_tokens=max_new_tokens))
+        calls = []
+        jitted = srv._decode
+
+        def counted(p, state, tokens, pos, lanes):
+            calls.append((srv.stats.engine_steps, np.asarray(pos),
+                          np.asarray(lanes)))
+            return jitted(p, state, tokens, pos, lanes)
+
+        srv._decode = counted
+        return srv, calls
+
+    def test_slots_at_distinct_positions_decode_in_one_call(self):
+        """Three slots at three cache positions: one decode call, each lane
+        at its own position, every slot's token emitted."""
+        srv, calls = self._counted_server(batch_size=4, max_new_tokens=8)
+        for n in (5, 7, 9):
+            srv.submit(np.arange(n, dtype=np.int32))
+        srv.engine_step()
+        assert len(calls) == 1
+        _, pos, lanes = calls[0]
+        assert pos.shape == (4,) and pos.dtype == np.int32
+        assert pos.tolist() == [5, 7, 9, 0]
+        assert lanes.tolist() == [True, True, True, False]
+        assert [len(s.generated) for s in srv.slots[:3]] == [2, 2, 2]
+        assert [s.pos for s in srv.slots[:3]] == [6, 8, 10]
+        assert srv.stats.decode_calls == 1 and srv.stats.tokens == 6
+
+    def test_decode_calls_count_steps_that_decode(self):
+        """Over a drained run with slots refilled at staggered positions,
+        ``ServerStats.decode_calls`` equals the engine steps that decoded,
+        each made exactly one decode call, and every decoded lane is one
+        token."""
+        srv, calls = self._counted_server(batch_size=2, max_new_tokens=4)
+        for n in (5, 9, 6, 11, 3):
+            srv.submit(np.arange(n, dtype=np.int32))
+        srv.run_until_drained()
+        steps = [c[0] for c in calls]
+        assert len(set(steps)) == len(steps)  # one call a step
+        assert srv.stats.decode_calls == len(steps) == srv.stats.engine_steps
+        assert any(len(set(c[1][c[2]].tolist())) > 1 for c in calls)
+        lanes = sum(int(c[2].sum()) for c in calls)
+        assert lanes == srv.stats.tokens - srv.stats.prefill_calls == 5 * 3
+
 
 class TestClusterServerMeasured:
     def test_measured_ordering_matches_simulation(self):

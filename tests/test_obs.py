@@ -121,7 +121,7 @@ def test_server_spans_and_stats(tmp_path):
             assert set(stats) == {"rid", "slot", "len"}
             assert stats["len"] == len(prompts[stats["rid"]])
         elif name == "repro.server.decode":
-            assert set(stats) == {"pos", "lanes"} and stats["lanes"] >= 1
+            assert set(stats) == {"max_pos", "lanes"} and stats["lanes"] >= 1
             lanes += stats["lanes"]
     steps = [s[3]["step"] for s in spans if s[0] == "repro.server.engine_step"]
     assert steps == list(range(1, st.engine_steps + 1))
@@ -160,7 +160,7 @@ def test_serving_program_names():
     lanes = jnp.ones((2,), bool)
     dec = srv._decode.lower(srv.params, srv.state,
                             jnp.zeros((2, 1), jnp.int32),
-                            jnp.asarray(3, jnp.int32), lanes)
+                            jnp.asarray([3, 5], jnp.int32), lanes)
     pre = srv._prefill.lower(srv.params,
                              {"tokens": jnp.zeros((2, 8), jnp.int32)},
                              srv.state, lanes)
