@@ -18,6 +18,7 @@ from .base import (  # noqa: F401
 from . import (
     arctic_480b,
     granite_3_2b,
+    granite_4_0_h_micro,
     hubert_xlarge,
     llama4_scout_17b_a16e,
     phi4_mini_3_8b,
@@ -35,6 +36,7 @@ _REGISTRY: Dict[str, ModelConfig] = {
         qwen3_32b,
         phi4_mini_3_8b,
         granite_3_2b,
+        granite_4_0_h_micro,
         rwkv6_7b,
         llama4_scout_17b_a16e,
         arctic_480b,

@@ -53,7 +53,9 @@ class SSMConfig:
     state_dim: int = 64  # mamba2 N (ssm_state)
     expand: int = 2  # mamba2 inner expansion
     conv_dim: int = 4  # mamba2 short conv width
-    scan_chunk: int = 0  # >0: remat the time scan per chunk (trains long seqs)
+    # mamba2: steps a chunk of the scan kernel (0: 256), and on the ref
+    # backend the chunk of the backward pass's recompute (trains long seqs)
+    scan_chunk: int = 0
 
 
 @dataclass(frozen=True)
@@ -80,6 +82,16 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid_attn_every: int = 0  # zamba2: shared attn block every k-th layer
+    # granite-4.0-h: the mixer of each layer ('mamba' | 'attention'), each
+    # layer with its own weights and its own MLP; () = one kind throughout
+    layer_types: Tuple[str, ...] = ()
+    # 'rope' | 'nope' (attention without positional encoding)
+    position_embedding: str = "rope"
+    # Granite multipliers; the defaults leave the model as it was
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # softmax scale; 0 = 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0  # logits are divided by it
     # modality frontend stubs (assignment: embeddings are precomputed inputs)
     frontend: Optional[str] = None  # 'vision' | 'audio'
     num_prefix_embeds: int = 0  # vision patch slots in the token stream
@@ -113,7 +125,17 @@ class ModelConfig:
 
     @property
     def has_attention(self) -> bool:
-        return self.family != "ssm" or self.hybrid_attn_every > 0
+        return (self.family != "ssm" or self.hybrid_attn_every > 0
+                or "attention" in self.layer_types)
+
+    @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The shortest run of ``layer_types`` that repeats to all of it."""
+        t = self.layer_types
+        for p in range(1, len(t) + 1):
+            if len(t) % p == 0 and t == t[:p] * (len(t) // p):
+                return t[:p]
+        return t
 
     @property
     def is_encoder_only(self) -> bool:
@@ -223,6 +245,10 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         )
     if cfg.ssm is not None:
         changes["ssm"] = dataclasses.replace(cfg.ssm, head_dim=16, state_dim=8)
+    if cfg.layer_types:
+        # one whole period, so every kind of layer is present
+        changes["layer_types"] = cfg.layer_period
+        changes["num_layers"] = len(cfg.layer_period)
     if cfg.hybrid_attn_every:
         changes["hybrid_attn_every"] = 2
         changes["num_layers"] = 4
@@ -236,6 +262,15 @@ def param_count(cfg: ModelConfig) -> int:
     if cfg.qkv_bias:
         attn += cfg.q_dim + 2 * cfg.kv_dim
     per_layer = 2 * d  # norms
+    if cfg.layer_types:
+        d_in, N = cfg.ssm.expand * d, cfg.ssm.state_dim
+        H, ch = d_in // cfg.ssm.head_dim, d_in + 2 * N
+        mamba = (d * (2 * d_in + 2 * N + H) + cfg.ssm.conv_dim * ch + ch
+                 + 3 * H + d_in + d_in * d)
+        n_m = cfg.layer_types.count("mamba")
+        per_layer += 3 * d * cfg.d_ff
+        return (L * per_layer + n_m * mamba + (L - n_m) * attn
+                + cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + d)
     if cfg.family == "ssm" and cfg.ssm and cfg.ssm.kind == "rwkv6":
         h = d // cfg.ssm.head_dim
         tmix = 4 * d * d + d * d  # r,k,v,g,o projections

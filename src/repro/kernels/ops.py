@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_pallas
+from .mamba2_scan import mamba2_ssd_pallas
 from .rmsnorm import rmsnorm_pallas
 from .rwkv6_scan import rwkv6_scan_pallas
 from .swiglu import swiglu_pallas
@@ -39,6 +40,7 @@ __all__ = [
     "swiglu",
     "flash_attention",
     "rwkv6_scan",
+    "mamba2_scan",
     "DECODE_ATTENTION_PATH",
 ]
 
@@ -171,3 +173,39 @@ def rwkv6_scan(
     )
     y, s_out = fn(r, k, v, w, u, s0)
     return y[:, :, :S], s_out
+
+
+def mamba2_scan(
+    x: jax.Array,  # (B, S, H, P)
+    Bmat: jax.Array,  # (B, S, N)
+    Cmat: jax.Array,  # (B, S, N)
+    dA: jax.Array,  # (B, S, H) log decay, dt * A
+    dt: jax.Array,  # (B, S, H)
+    state: Optional[jax.Array] = None,  # (B, H, P, N)
+    *,
+    chunk: int = 256,
+) -> Tuple[jax.Array, jax.Array]:
+    """The Mamba2 SSD scan: (y (B,S,H,P) f32, final state (B,H,P,N) f32).
+    ``chunk`` is the kernel's chunk of steps, and on the ref backend the
+    chunk of the recompute in the backward pass."""
+    if _BACKEND == "ref":
+        return ref.mamba2_ssd_scan(x, Bmat, Cmat, jnp.exp(dA), dt, state,
+                                   remat_chunk=chunk)
+    B, S, H, P = x.shape
+    N = Bmat.shape[-1]
+    q = min(S, chunk)
+    pad = -S % q
+    if pad:
+        # padded steps carry x = B = C = 0, dt = 0 and log decay 0: they
+        # leave the state as it was, and their outputs are cut off below
+        x, Bmat, Cmat, dA, dt = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (x, Bmat, Cmat, dA, dt))
+    s0 = state if state is not None else jnp.zeros((B, H, P, N), jnp.float32)
+    fn = _ref_vjp(
+        lambda *a: mamba2_ssd_pallas(*a, chunk=q, interpret=_INTERPRET),
+        lambda x, b, c, a, d, s: ref.mamba2_ssd_scan(x, b, c, jnp.exp(a), d,
+                                                     s),
+    )
+    y, s_out = fn(x, Bmat, Cmat, dA, dt, s0)
+    return y[:, :S], s_out

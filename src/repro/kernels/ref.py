@@ -21,13 +21,17 @@ def mamba2_ssd_scan(
     decay: jax.Array,  # (B, S, H) = exp(dt * A)
     dt: jax.Array,  # (B, S, H)
     state: Optional[jax.Array] = None,  # (B, H, P, N)
+    remat_chunk: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Mamba2 SSD recurrence (the inner loop of models.mamba2):
 
         h_t = decay_t * h_{t-1} + dt_t * (x_t B_t^T)
         y_t = h_t C_t
 
-    Returns (y: (B,S,H,P) f32, final_state: (B,H,P,N) f32).
+    Returns (y: (B,S,H,P) f32, final_state: (B,H,P,N) f32).  With
+    ``remat_chunk`` dividing S (and below it) the backward pass keeps the
+    state at chunk boundaries only and recomputes inside each chunk:
+    O(S/chunk) residuals instead of O(S), the same numbers.
     """
     B, S, H, P = x.shape
     N = Bmat.shape[-1]
@@ -47,7 +51,17 @@ def mamba2_ssd_scan(
     xs = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(Bmat, 1, 0),
           jnp.moveaxis(Cmat, 1, 0), jnp.moveaxis(decay, 1, 0),
           jnp.moveaxis(dt, 1, 0))
-    h_final, ys = jax.lax.scan(step, s0, xs)
+    c = remat_chunk
+    if c and S % c == 0 and S > c:
+        @jax.checkpoint
+        def chunk_body(h, inp):
+            return jax.lax.scan(step, h, inp)
+
+        resh = lambda a: a.reshape((S // c, c) + a.shape[1:])
+        h_final, ys = jax.lax.scan(chunk_body, s0, tuple(map(resh, xs)))
+        ys = ys.reshape((S,) + ys.shape[2:])
+    else:
+        h_final, ys = jax.lax.scan(step, s0, xs)
     return jnp.moveaxis(ys, 0, 1), h_final
 
 

@@ -302,15 +302,21 @@ def calibrated_costs(
     """Correct for HloCostAnalysis counting while-loop (scan) bodies once:
     lower the same cell UNROLLED at depth u and 2u, then extrapolate
     total = f(u) + (L/u - 1) * (f(2u) - f(u)).  u = hybrid_attn_every for
-    the hybrid arch (its repeating unit spans `every` layers), else 1."""
+    the hybrid arch (its repeating unit spans `every` layers), the period
+    of a ``layer_types`` arch, else 1."""
     cfg = get_config(arch)
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
-    u = cfg.hybrid_attn_every if cfg.family == "hybrid" else 1
+    if cfg.layer_types:
+        u = len(cfg.layer_period)
+    else:
+        u = cfg.hybrid_attn_every if cfg.family == "hybrid" else 1
     probes = {}
     for n in (u, 2 * u):
         ov = dict(overrides or {})
         ov.update(num_layers=n, scan_layers=False)
+        if cfg.layer_types:
+            ov["layer_types"] = cfg.layer_period * (n // u)
         compiled = _compile_cell(arch, shape_name, mesh, ov)
         cost = compiled.cost_analysis()
         probes[n] = {

@@ -1,4 +1,5 @@
-"""GQA attention block: RoPE, optional qk-norm / QKV bias, KV cache.
+"""GQA attention block: RoPE (or none, ``position_embedding="nope"``),
+optional qk-norm / QKV bias, KV cache.
 
 Prefill/train run the flash path (`kernels.ops.flash_attention`); decode
 attends one query against the full padded cache with a position mask —
@@ -75,15 +76,17 @@ def attention_heads(
     if cfg.qk_norm:
         q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    scale = cfg.attention_multiplier or None
 
     qh = q.transpose(0, 2, 1, 3)  # (B,H,S,hd)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
 
     if kv_cache is None:
-        out = ops.flash_attention(qh, kh, vh, causal=cfg.causal)
+        out = ops.flash_attention(qh, kh, vh, causal=cfg.causal, scale=scale)
         new_cache = None
     else:
         ck, cv = kv_cache  # (B, Hkv, T, hd)
@@ -93,13 +96,15 @@ def attention_heads(
         if S > 1:
             # prefill: the new block is the whole context — attend causally
             # within it; the cache write above is just state installation
-            out = ops.flash_attention(qh, kh, vh, causal=cfg.causal)
+            out = ops.flash_attention(qh, kh, vh, causal=cfg.causal,
+                                      scale=scale)
         else:
             # decode: one query against the valid prefix of each lane's cache
             T = ck.shape[2]
             valid = jnp.arange(T)[None, :] <= jnp.reshape(cache_pos, (-1, 1))
             valid = jnp.broadcast_to(valid, (B, T))
-            out = ops.flash_attention(qh, ck, cv, causal=False, kv_mask=valid)
+            out = ops.flash_attention(qh, ck, cv, causal=False, scale=scale,
+                                      kv_mask=valid)
 
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return out, new_cache

@@ -1,13 +1,18 @@
-"""Mamba2 (SSD) block for the zamba2 hybrid backbone.
+"""Mamba2 (SSD) mixer: zamba2's backbone and granite-4.0-h's Mamba layers.
 
-State-space recurrence per head (P = head_dim, N = state_dim):
+State-space recurrence per head (P = head_dim, N = state_dim), B and C
+shared by all heads (one group):
 
     h_t = exp(A * dt_t) * h_{t-1} + dt_t * (x_t  B_t^T)     h: (P, N)
     y_t = h_t C_t + D * x_t
 
-with a width-4 causal depthwise conv on (x, B, C) and a silu(z) gate.
-Sequential lax.scan over time (chunked SSD left to the kernel layer);
-decode carries {conv: (B, w-1, ch), ssm: (B, H, P, N)} — O(1) per token.
+with a width-4 causal depthwise conv (with bias) and silu on (x, B, C),
+``dt = softplus(dt_raw + dt_bias)``, ``A = -exp(A_log)``, then the gated
+RMSNorm ``rms(y * silu(z)) * w`` over the whole inner width (d_inner /
+n_groups with one group) and the output projection.  Prompts run the scan
+through ``kernels.ops.mamba2_scan`` (the Pallas kernel on the TPU); decode
+is a one-step update of the carried {conv: (B, w-1, ch), ssm: (B, H, P, N)}
+— O(1) per token.
 """
 from __future__ import annotations
 
@@ -17,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .layers import dense_init, group_norm
+from ..kernels import ops
+from .layers import dense_init
 
 __all__ = ["mamba2_init", "mamba2_block", "mamba2_state_init"]
 
@@ -91,53 +97,25 @@ def mamba2_block(
     xs = xs.reshape(B, S, H, P)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
-    a = -jnp.exp(p["a_log"])  # (H,)
-    decay = jnp.exp(dt * a)  # (B,S,H)
+    dA = dt * -jnp.exp(p["a_log"])  # log decay (B,S,H)
 
-    s0 = (
-        state["ssm"]
-        if state is not None
-        else jnp.zeros((B, H, P, N), jnp.float32)
-    )
-
-    def step(h, inp):
-        xt, Bt, Ct, dct, dtt = inp  # (B,H,P), (B,N), (B,N), (B,H), (B,H)
-        upd = dtt[..., None, None] * (
-            xt.astype(jnp.float32)[..., :, None] * Bt.astype(jnp.float32)[:, None, None, :]
-        )  # (B,H,P,N)
-        h = dct[..., None, None] * h + upd
-        yt = jnp.einsum("bhpn,bn->bhp", h, Ct.astype(jnp.float32))
-        return h, yt
-
-    xs_t = jnp.moveaxis(xs, 1, 0)
-    B_t = jnp.moveaxis(Bmat, 1, 0)
-    C_t = jnp.moveaxis(Cmat, 1, 0)
-    dc_t = jnp.moveaxis(decay, 1, 0)
-    dt_t = jnp.moveaxis(dt, 1, 0)
-    chunk = cfg.ssm.scan_chunk
-    if chunk and S % chunk == 0 and S > chunk:
-        # time-chunked remat: the backward pass only keeps the recurrent
-        # state at chunk boundaries and recomputes inside each chunk —
-        # O(S/chunk) residuals instead of O(S) (the zamba2 train_4k memory
-        # fix, EXPERIMENTS.md §Perf)
-        def chunk_body(h, inp):
-            h, ys = jax.lax.scan(step, h, inp)
-            return h, ys
-
-        chunk_body = jax.checkpoint(chunk_body)
-        resh = lambda a: a.reshape((S // chunk, chunk) + a.shape[1:])
-        h_final, ys = jax.lax.scan(
-            chunk_body, s0, tuple(resh(a) for a in (xs_t, B_t, C_t, dc_t, dt_t))
-        )
-        ys = ys.reshape((S,) + ys.shape[2:])
+    if state is not None and S == 1:
+        # decode: one step of the recurrence on the carried state
+        h_final = (jnp.exp(dA[:, 0])[..., None, None] * state["ssm"]
+                   + (dt[:, 0, :, None] * xs[:, 0].astype(jnp.float32))[..., None]
+                   * Bmat[:, 0, None, None, :].astype(jnp.float32))
+        y = jnp.einsum("bhpn,bn->bhp", h_final,
+                       Cmat[:, 0].astype(jnp.float32))[:, None]
     else:
-        h_final, ys = jax.lax.scan(step, s0, (xs_t, B_t, C_t, dc_t, dt_t))
-    y = jnp.moveaxis(ys, 0, 1)  # (B,S,H,P) f32
+        s0 = state["ssm"] if state is not None else None
+        y, h_final = ops.mamba2_scan(xs, Bmat, Cmat, dA, dt, s0,
+                                     chunk=cfg.ssm.scan_chunk or 256)
     y = y + p["d_skip"][None, None, :, None] * xs.astype(jnp.float32)
-    y = y.reshape(B, S, d_in).astype(x.dtype)
-
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    y = group_norm(y, H) * p["norm_scale"]
+    y = y.reshape(B, S, d_in) * jax.nn.silu(z.astype(jnp.float32))
+    # gated RMSNorm over the inner width (one group), in float32
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    y = y.astype(x.dtype) * p["norm_scale"]
     out = jnp.einsum("bsf,fd->bsd", y, p["out_proj"]["w"])
 
     new_state = {"conv": new_conv, "ssm": h_final} if state is not None else None

@@ -1,12 +1,16 @@
 """Config-driven model assembly: init / forward / loss / decode.
 
-One code path covers all ten assigned architectures:
+One code path covers every registered architecture:
 
   dense | moe | vlm | audio : [ln -> attention -> ln -> FFN/MoE] x L
   ssm (rwkv6)               : [ln -> time-mix -> ln -> channel-mix] x L
   hybrid (zamba2)           : [ln -> mamba2] x L (+ one *shared* attn+FFN
                               block invoked every cfg.hybrid_attn_every
                               layers, weights reused, per-invocation KV)
+  layer_types (granite-4.0-h): [ln -> mamba2 | attention -> ln -> FFN] x L,
+                              the mixer of each layer from cfg.layer_types,
+                              each layer with its own weights; scanned by
+                              period, decode state {mamba, k, v} side by side
 
 Layers are stacked and run under ``lax.scan`` (keeps the HLO O(1) in depth —
 essential for 64-layer 32B configs on the dry-run) with optional per-layer
@@ -21,6 +25,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ModelConfig
 from .attention import (
     attention,
@@ -42,8 +47,8 @@ from .rwkv6 import (
 from .sharding import constrain
 
 __all__ = ["init_params", "forward", "loss_fn", "init_decode_state",
-           "decode_step", "transformer_block_tp", "transformer_block_ref",
-           "tp_block_specs"]
+           "decode_step", "RECURRENT_STATE", "reset_recurrent_lanes",
+           "transformer_block_tp", "transformer_block_ref", "tp_block_specs"]
 
 ZERO_AUX = lambda: {"load_balance": jnp.zeros((), jnp.float32),
                     "router_z": jnp.zeros((), jnp.float32)}
@@ -89,6 +94,27 @@ def _layer_init(key, cfg: ModelConfig, *, dtype) -> Dict:
     return layer
 
 
+def _pattern_init(key, cfg: ModelConfig, *, dtype) -> Dict:
+    """Layers of a ``layer_types`` model: norms and FFN of every layer
+    stacked (L, ...); the Mamba mixers (``mamba``) and the attention
+    mixers (``attn``) each stacked on their own in layer order."""
+    k_common, k_mamba, k_attn = jax.random.split(key, 3)
+
+    def common(k):
+        return {"ln1": rmsnorm_init(cfg.d_model, dtype=dtype),
+                "ln2": rmsnorm_init(cfg.d_model, dtype=dtype),
+                "ffn": mlp_init(k, cfg, dtype=dtype)}
+
+    layers = jax.vmap(common)(jax.random.split(k_common, cfg.num_layers))
+    for name, kind, k, fn in (("mamba", "mamba", k_mamba, mamba2_init),
+                              ("attn", "attention", k_attn, attn_init)):
+        n = cfg.layer_types.count(kind)
+        if n:
+            layers[name] = jax.vmap(functools.partial(fn, cfg=cfg, dtype=dtype))(
+                jax.random.split(k, n))
+    return layers
+
+
 def init_params(key, cfg: ModelConfig) -> Dict:
     dtype = jnp.dtype(cfg.dtype)
     k_embed, k_layers, k_head, k_shared = jax.random.split(key, 4)
@@ -98,10 +124,13 @@ def init_params(key, cfg: ModelConfig) -> Dict:
         # evenly; the pad region is zero and masked out of loss/decode
         params["embed"] = (jax.random.normal(k_embed, (cfg.padded_vocab, cfg.d_model))
                            * 0.02).astype(dtype)
-    layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    params["layers"] = jax.vmap(
-        functools.partial(_layer_init, cfg=cfg, dtype=dtype)
-    )(layer_keys)
+    if cfg.layer_types:
+        params["layers"] = _pattern_init(k_layers, cfg, dtype=dtype)
+    else:
+        layer_keys = jax.random.split(k_layers, cfg.num_layers)
+        params["layers"] = jax.vmap(
+            functools.partial(_layer_init, cfg=cfg, dtype=dtype)
+        )(layer_keys)
     if cfg.hybrid_attn_every:
         params["shared_block"] = {
             "ln1": rmsnorm_init(cfg.d_model, dtype=dtype),
@@ -125,9 +154,20 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int) -> Dict:
     dtype = jnp.dtype(cfg.dtype)
     L = cfg.num_layers
 
-    def stack(tree):
-        return jax.tree.map(lambda a: jnp.broadcast_to(a, (L,) + a.shape).copy(), tree)
+    def stack(tree, n=L):
+        return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape).copy(), tree)
 
+    if cfg.layer_types:
+        state = {}
+        n_m = cfg.layer_types.count("mamba")
+        n_a = cfg.layer_types.count("attention")
+        if n_m:
+            state["mamba"] = stack(mamba2_state_init(cfg, batch, dtype=dtype), n_m)
+        if n_a:
+            kv_shape = (n_a, batch, cfg.num_kv_heads, max_seq, cfg.head_dim)
+            state["k"] = jnp.zeros(kv_shape, dtype)
+            state["v"] = jnp.zeros(kv_shape, dtype)
+        return state
     if cfg.family == "ssm":
         return {"rwkv": stack(rwkv6_state_init(cfg, batch, dtype=dtype))}
     if cfg.family == "hybrid":
@@ -142,6 +182,26 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int) -> Dict:
     return {"k": jnp.zeros(kv_shape, dtype), "v": jnp.zeros(kv_shape, dtype)}
 
 
+#: decode-state entries that carry a recurrent state (Mamba conv and SSM,
+#: RWKV shift and WKV), which a prompt starts from
+RECURRENT_STATE = ("mamba", "rwkv")
+
+
+def reset_recurrent_lanes(state: Dict, lanes: jax.Array) -> Dict:
+    """``state`` with the recurrent state of the batch lanes set in
+    ``lanes`` ((B,) bool) zeroed: a prompt prefilled there starts from
+    nothing, not from the state a previous request left.  K/V caches are
+    left as they are: a prefill writes every position its keys reach."""
+    out = dict(state)
+    for name in RECURRENT_STATE:
+        if name in state:
+            out[name] = jax.tree.map(
+                lambda a: jnp.where(
+                    lanes.reshape((1, -1) + (1,) * (a.ndim - 2)),
+                    jnp.zeros((), a.dtype), a), state[name])
+    return out
+
+
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
@@ -149,6 +209,8 @@ def _embed_inputs(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:
     if cfg.frontend == "audio":
         return batch["embeds"].astype(jnp.dtype(cfg.dtype))
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.frontend == "vision" and "image_embeds" in batch:
         img = batch["image_embeds"].astype(x.dtype)
         x = jax.lax.dynamic_update_slice(x, img, (0, 0, 0))
@@ -165,6 +227,13 @@ def _keep_lanes(lanes, old, new):
                                n, o), old, new)
 
 
+def _residual(cfg: ModelConfig, x: jax.Array, h: jax.Array) -> jax.Array:
+    """``x + h``, the branch scaled by the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
 def _attn_layer_body(cfg, layer, x, positions, kv, cache_pos,
                      cache_lanes=None):
     h, new_kv = attention(
@@ -172,14 +241,83 @@ def _attn_layer_body(cfg, layer, x, positions, kv, cache_pos,
         positions=positions, kv_cache=kv, cache_pos=cache_pos,
         cache_lanes=cache_lanes,
     )
-    x = x + h
+    x = _residual(cfg, x, h)
     aux = ZERO_AUX()
     if cfg.moe is not None:
         h, aux = moe_block(layer["moe"], cfg, rmsnorm(layer["ln2"], x, cfg.norm_eps))
     else:
         h = mlp(layer["ffn"], cfg, rmsnorm(layer["ln2"], x, cfg.norm_eps))
-    x = constrain(x + h, "hidden")
+    x = constrain(_residual(cfg, x, h), "hidden")
     return x, new_kv, aux
+
+
+def _pattern_layers(cfg, params, x, positions, cache, cache_pos,
+                    cache_lanes):
+    """The layers of a ``layer_types`` model, scanned over the repeats of
+    its period (the HLO holds one period, whatever the depth).  Each layer:
+    ``x += r * mixer(rms(x))``, ``x += r * ffn(rms(x))``, the parts under
+    the ``mamba2``, ``attention`` and ``mlp`` scopes.  The decode state's
+    per-layer entries are read and written in place in the scan's carry.
+    Returns (x, new cache or None)."""
+    period = cfg.layer_period
+    n = cfg.num_layers // len(period)
+    count = {k: period.count(k) for k in ("mamba", "attention")}
+    layers = params["layers"]
+    use_cache = cache is not None
+
+    def at(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            tree)
+
+    def put(tree, new, i):
+        return jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                a, u.astype(a.dtype), i, 0), tree, new)
+
+    def body(carry, p):
+        # every layer's weights are sliced from the whole stacks where they
+        # are used: a per-period slab of weights would be copied whole on
+        # every step of the scan
+        h, st = carry
+        seen = {"mamba": 0, "attention": 0}
+        for j, kind in enumerate(period):
+            lw = at({k: layers[k] for k in ("ln1", "ln2", "ffn")},
+                    p * len(period) + j)
+            idx = p * count[kind] + seen[kind]
+            seen[kind] += 1
+            if kind == "mamba":
+                with obs.scope("mamba2"):
+                    old = at(st["mamba"], idx) if use_cache else None
+                    out, new = mamba2_block(
+                        at(layers["mamba"], idx), cfg,
+                        rmsnorm(lw["ln1"], h, cfg.norm_eps), state=old)
+                    if use_cache:
+                        new = _keep_lanes(cache_lanes, old, new)
+                        st = dict(st, mamba=put(st["mamba"], new, idx))
+            else:
+                with obs.scope("attention"):
+                    kv = (at(st["k"], idx), at(st["v"], idx)) \
+                        if use_cache else None
+                    out, new_kv = attention(
+                        at(layers["attn"], idx), cfg,
+                        rmsnorm(lw["ln1"], h, cfg.norm_eps),
+                        positions=positions, kv_cache=kv,
+                        cache_pos=cache_pos, cache_lanes=cache_lanes)
+                    if use_cache:
+                        st = dict(st, k=put(st["k"], new_kv[0], idx),
+                                  v=put(st["v"], new_kv[1], idx))
+            h = _residual(cfg, h, out)
+            with obs.scope("mlp"):
+                out = mlp(lw["ffn"], cfg, rmsnorm(lw["ln2"], h, cfg.norm_eps))
+            h = constrain(_residual(cfg, h, out), "hidden")
+        return (h, st), 0
+
+    if cfg.remat:
+        body = _maybe_checkpoint(cfg, body)
+    (x, st), _ = _scan_or_loop(body, (x, cache if use_cache else {}),
+                               jnp.arange(n), n, cfg.scan_layers)
+    return x, (st if use_cache else None)
 
 
 def _rwkv_layer_body(cfg, layer, x, state):
@@ -215,12 +353,16 @@ def _scan_or_loop(body, carry, xs, length: int, use_scan: bool):
 def apply_head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
     """Final-norm'd hidden -> (padded-)vocab logits in f32 (accumulated and
     returned in f32: bf16 logits would round away the gaps greedy decoding
-    and the loss depend on)."""
+    and the loss depend on), divided by ``cfg.logits_scaling``."""
     if cfg.tie_embeddings:
-        return jnp.einsum("bsd,vd->bsv", x, params["embed"],
-                          preferred_element_type=jnp.float32)
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"]["w"],
-                      preferred_element_type=jnp.float32)
+        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"],
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"]["w"],
+                            preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def forward(
@@ -249,7 +391,12 @@ def forward(
 
     new_cache: Optional[Dict] = None
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        x, new_cache = _pattern_layers(cfg, params, x, positions, cache, pos0,
+                                       cache_lanes)
+        aux = ZERO_AUX()
+
+    elif cfg.family == "ssm":
         use_cache = cache is not None
 
         def body(carry, layer_and_st):

@@ -174,6 +174,14 @@ def cache_specs(cfg: ModelConfig, mesh: Mesh) -> Dict:
         kv = P(None, dp, TP, None, None)
     else:
         kv = P(None, dp, None, TP, None)
+    mamba = {"conv": P(None, dp, None, TP), "ssm": P(None, dp, TP, None, None)}
+    if cfg.layer_types:
+        specs = {}
+        if "mamba" in cfg.layer_types:
+            specs["mamba"] = mamba
+        if "attention" in cfg.layer_types:
+            specs["k"] = specs["v"] = kv
+        return specs
     if cfg.family == "ssm":
         return {
             "rwkv": {
@@ -184,10 +192,7 @@ def cache_specs(cfg: ModelConfig, mesh: Mesh) -> Dict:
         }
     if cfg.family == "hybrid":
         return {
-            "mamba": {
-                "conv": P(None, dp, None, TP),
-                "ssm": P(None, dp, TP, None, None),
-            },
+            "mamba": mamba,
             "shared_k": kv,
             "shared_v": kv,
         }

@@ -7,7 +7,11 @@ queue — the standard continuous-batching pattern (vLLM-style, cache-slot
 granularity) built on ``models.decode_step``.
 
 Prefill is per-request: it runs over the whole slot batch with the prompt
-in the slot's lane, and only that lane of the new cache is kept.  Decode
+in the slot's lane, and only that lane of the new cache is kept.  The
+lane's recurrent state (Mamba conv and SSM, RWKV shift and WKV) is zeroed
+first, so a request never starts from the state its slot's previous
+request left; the output head runs at the last position only, the one
+whose logits give the first token.  Decode
 is one call per engine step for every active slot, each lane at its own
 cache position (``decode_step`` with a ``(B,)`` position); the lanes of
 empty slots keep their cache.
@@ -39,7 +43,8 @@ import numpy as np
 
 from .. import obs
 from ..configs.base import ModelConfig
-from ..models import decode_step, forward, init_decode_state
+from ..models import (RECURRENT_STATE, decode_step, forward,
+                      init_decode_state, reset_recurrent_lanes)
 
 __all__ = ["ServerConfig", "BatchedServer", "RequestTiming", "ServerStats"]
 
@@ -102,13 +107,16 @@ class RequestTiming:
 @dataclass
 class ServerStats:
     """Work counters since the server was built or last reset: engine
-    steps, prefill calls, decode calls (one per engine step that decodes)
-    and tokens emitted (one per prefill, one per decoded lane)."""
+    steps, prefill calls, decode calls (one per engine step that decodes),
+    tokens emitted (one per prefill, one per decoded lane) and state resets
+    (lanes whose recurrent state a prefill zeroed; none for a model that
+    carries only K/V)."""
 
     engine_steps: int = 0
     prefill_calls: int = 0
     decode_calls: int = 0
     tokens: int = 0
+    state_resets: int = 0
 
     def to_json(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -143,10 +151,14 @@ class BatchedServer:
         def decode(p, state, tokens, pos, lanes):
             return decode_step(cfg, p, state, tokens, pos, cache_lanes=lanes)
 
+        # lanes a prefill resets: one for a model with recurrent state
+        self._resets = int(any(k in self.state for k in RECURRENT_STATE))
+
         def prefill(p, batch, state, lanes):
+            state = reset_recurrent_lanes(state, lanes)
             logits, new, _ = forward(cfg, p, batch, cache=state,
                                      cache_pos=jnp.zeros((), jnp.int32),
-                                     cache_lanes=lanes)
+                                     cache_lanes=lanes, head_mode="last")
             return logits, new
 
         # one cached jit each — a fresh lambda per request would recompile
@@ -215,11 +227,12 @@ class BatchedServer:
                 self.params, {"tokens": jnp.asarray(toks)}, self.state,
                 self._lanes([slot_idx]))
             with obs.span("server.fetch"):
-                nxt = int(jnp.argmax(logits[slot_idx, -1]))
+                nxt = int(jnp.argmax(logits[slot_idx]))
             if self.scfg.keep_logits:
                 self.logits.setdefault(rid, []).append(
-                    np.asarray(logits[slot_idx, -1], np.float32))
+                    np.asarray(logits[slot_idx], np.float32))
         self.stats.prefill_calls += 1
+        self.stats.state_resets += self._resets
         self.stats.tokens += 1
         slot = self.slots[slot_idx]
         slot.request_id = rid

@@ -180,8 +180,8 @@ def test_mamba2_ssd_kernel(B, H, S, P, N, chunk):
     decay = jnp.asarray(RNG.uniform(0.6, 0.95, (B, S, H)), jnp.float32)
     dt = jnp.asarray(RNG.uniform(0.1, 0.9, (B, S, H)), jnp.float32)
     s0 = jnp.zeros((B, H, P, N), jnp.float32)
-    got_y, got_s = mamba2_ssd_pallas(x, Bm, Cm, decay, dt, s0, chunk=chunk,
-                                     interpret=True)
+    got_y, got_s = mamba2_ssd_pallas(x, Bm, Cm, jnp.log(decay), dt, s0,
+                                     chunk=chunk, interpret=True)
     want_y, want_s = ref.mamba2_ssd_scan(x, Bm, Cm, decay, dt, s0)
     np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
                                rtol=1e-4, atol=1e-4)

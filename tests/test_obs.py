@@ -76,7 +76,8 @@ def test_server_spans_and_stats(tmp_path):
     assert srv.stats.decode_calls > 0
     srv.reset()
     assert srv.stats.to_json() == {"engine_steps": 0, "prefill_calls": 0,
-                                   "decode_calls": 0, "tokens": 0}
+                                   "decode_calls": 0, "tokens": 0,
+                                   "state_resets": 0}
 
     runs = {"decode": 0}
     jitted = srv._decode

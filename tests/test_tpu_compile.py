@@ -50,6 +50,8 @@ def _compiled_text(fn, *shapes) -> str:
 # granite-3-2b: d_model 2048, d_ff 8192, 32/8 heads of 64; 4096 rows is a
 # (2, 2048) prefill.  rwkv6-7b: 64 heads of 64; S=1000 is not a multiple of
 # the kernel's 128-step chunk, so the ops wrapper pads it.
+# granite-4.0-h-micro's Mamba2: 64 heads of P=64, d_state N=128, chunk 256,
+# a 4096-token prompt over the server's 4 lanes.
 KERNELS = {
     "rmsnorm": (lambda x, s: ops.rmsnorm(x, s),
                 [((4096, 2048), jnp.bfloat16), ((2048,), jnp.bfloat16)]),
@@ -62,6 +64,12 @@ KERNELS = {
     "rwkv6_scan": (lambda r, k, v, w, u: ops.rwkv6_scan(r, k, v, w, u),
                    [((1, 64, 1000, 64), jnp.bfloat16)] * 4
                    + [((64, 64), jnp.bfloat16)]),
+    "mamba2_scan": (lambda x, b, c, a, dt, s: ops.mamba2_scan(
+                        x, b, c, a, dt, s, chunk=256),
+                    [((4, 4096, 64, 64), jnp.bfloat16)]
+                    + [((4, 4096, 128), jnp.bfloat16)] * 2
+                    + [((4, 4096, 64), jnp.float32)] * 2
+                    + [((4, 64, 64, 128), jnp.float32)]),
 }
 
 
